@@ -22,14 +22,8 @@ pod-size row (``POD``, n_guests >= 128) runs the synth path alone -- the
 array path is skipped with a logged reason, since its host trace would be
 O(n_guests * n_windows * k).
 
-Every timed (case, runner) pair runs in a FRESH SUBPROCESS (``--worker``
-mode): on a small shared-CPU container the in-process sequence let earlier
-runners pollute later ones (allocator state, XLA autotuning, thermal
-throttle), which made the ``sharded_no_slower_at_scale`` ratio flap. A
-worker times exactly one runner and prints its JSON on stdout; the parent
-merges and computes the ratios. Set ``BENCH_ENGINE_IN_PROCESS=1`` to fall
-back to in-process timing (debugging, or environments where spawning is
-expensive).
+Every runner is timed in this one process: on a TPU the process that first
+touches JAX holds the chip, so a child process could not reach it.
 
 A steady-state churn case (ISSUE 6) times ``engine.run_churn`` under a
 Poisson arrival/departure fault schedule over mixed drift workloads against
@@ -53,8 +47,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import time
 import warnings
 
@@ -110,7 +102,7 @@ def _best_of(make, runner, traces, case, key) -> None:
 
 
 def _bench_case(n_guests: int, logical_per_guest: int, n_windows: int,
-                mesh, only: str | None = None) -> dict:
+                mesh, with_pallas: bool = False) -> dict:
     traces = np.stack([
         tr.generate(tr.TraceSpec(
             "redis", n_logical=logical_per_guest, hp_ratio=HP_RATIO,
@@ -179,27 +171,22 @@ def _bench_case(n_guests: int, logical_per_guest: int, n_windows: int,
         ("reference", simulate.run_multi_guest_reference),
         ("engine", run_engine),
         ("synth", run_synth),
-        ("pallas", run_pallas),
     ]
+    if with_pallas:
+        runners.append(("pallas", run_pallas))
     if mesh is not None:
         runners.append(("engine_sharded", run_sharded))
         runners.append(("host_sharded", run_host_sharded))
         if n_windows % 4 == 0:  # host-sharded stride must divide the chunk
             runners.append(("overlap", run_overlap))
-    if only is not None:
-        runners = [(n, r) for n, r in runners if n == only]
-        if not runners:
-            raise ValueError(f"unknown runner {only!r}")
     for name, runner in runners:
         _best_of(make, runner, traces, case, name)
-    if only is None:
-        _finalize_case(case)
+    _finalize_case(case)
     return case
 
 
 def _finalize_case(case: dict) -> None:
-    """The cross-runner ratios, computed once every timing key is present
-    (in one process, or merged from the per-runner worker subprocesses)."""
+    """The cross-runner ratios, computed once every timing key is present."""
     case["speedup"] = case["reference_s"] / case["engine_s"]
     case["synth_vs_engine"] = case["engine_s"] / case["synth_s"]
     if "pallas_s" in case:
@@ -340,67 +327,16 @@ def _multihost_wall() -> dict:
                 multihost_pod_guests=32, multihost_migrations=1)
 
 
-# --------------------------------------------------------------------------
-# per-runner worker subprocesses
-# --------------------------------------------------------------------------
-_WORKER_TAG = "BENCH_WORKER_RESULT "
-
-
-def _worker_main(req: dict) -> dict:
-    mesh = common.default_guest_mesh()
-    if req["kind"] == "grid":
-        n_guests, logical_per_guest, n_windows = GRID[req["index"]]
-        return _bench_case(n_guests, logical_per_guest, n_windows, mesh,
-                           only=req["runner"])
-    if req["kind"] == "pod":
-        return _pod_case(mesh)
-    if req["kind"] == "churn":
-        return _churn_case()
-    raise ValueError(f"unknown worker request {req!r}")
-
-
-def _run_worker(req: dict) -> dict:
-    """Time one (case, runner) pair in a fresh subprocess so runners cannot
-    pollute each other's wall clock. ``BENCH_ENGINE_IN_PROCESS=1`` falls
-    back to in-process timing."""
-    if os.environ.get("BENCH_ENGINE_IN_PROCESS"):
-        return _worker_main(req)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_engine", "--worker",
-         json.dumps(req)],
-        capture_output=True, text=True, env=env, timeout=1800)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"bench worker {req} failed:\nstdout:\n{proc.stdout}\n"
-            f"stderr:\n{proc.stderr}")
-    for line in reversed(proc.stdout.splitlines()):
-        if line.startswith(_WORKER_TAG):
-            return json.loads(line[len(_WORKER_TAG):])
-    raise RuntimeError(
-        f"bench worker {req} printed no result:\nstdout:\n{proc.stdout}\n"
-        f"stderr:\n{proc.stderr}")
-
-
 def run() -> dict:
     mesh = common.default_guest_mesh()
     n_devices = 1 if mesh is None else mesh.shape["guest"]
-    runner_names = ["reference", "engine", "synth"]
-    if mesh is not None:
-        runner_names += ["engine_sharded", "host_sharded"]
     cases = []
     for i, (n_guests, logical_per_guest, n_windows) in enumerate(GRID):
-        case: dict = {}
         # pallas-interpret is timed on the smallest row only (§16): the
         # interpreter's constant factor would dominate every larger row
         # without adding information
-        row_runners = runner_names + (["pallas"] if i == 0 else [])
-        if mesh is not None and n_windows % 4 == 0:
-            row_runners = row_runners + ["overlap"]
-        for runner in row_runners:
-            case.update(_run_worker(dict(kind="grid", index=i, runner=runner)))
-        _finalize_case(case)
+        case = _bench_case(n_guests, logical_per_guest, n_windows, mesh,
+                           with_pallas=i == 0)
         cases.append(case)
         sharded = (f" sharded[{n_devices}d] {case['engine_sharded_s']*1e3:8.1f} ms"
                    if "engine_sharded_s" in case else "")
@@ -419,12 +355,12 @@ def run() -> dict:
               f" synth {case['synth_s']*1e3:8.1f} ms"
               f" speedup {case['speedup']:5.2f}x{sharded}{host}{overlap}"
               f"{pallas}")
-    pod = _run_worker(dict(kind="pod"))
+    pod = _pod_case(mesh)
     cases.append(pod)
     print(f"  n_guests={pod['n_guests']:3d} n_logical={pod['n_logical']:6d} "
           f"windows={pod['n_windows']:3d}: synth {pod['synth_s']*1e3:8.1f} ms "
           f"(pod row; array path skipped)")
-    churn = _run_worker(dict(kind="churn"))
+    churn = _churn_case()
     cases.append(churn)
     print(f"  churn fleet {churn['n_guests']:3d} guests x "
           f"{churn['n_windows']} windows ({churn['n_fault_events']} fault "
@@ -492,10 +428,6 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
-    if len(sys.argv) >= 2 and sys.argv[1] == "--worker":
-        result = _worker_main(json.loads(sys.argv[2]))
-        print(_WORKER_TAG + json.dumps(result, default=float), flush=True)
-        sys.exit(0)
     r = run()
     print(f"min speedup at n_guests>=8: {r['min_speedup_at_scale']:.2f}x "
           f"(target >= {r['target_speedup_at_scale']}x) "

@@ -46,6 +46,9 @@ def main(argv=None):
         except OSError as e:
             ap.error(f"cannot write --json path {args.json!r}: {e}")
 
+    from repro.launch import compile_cache
+
+    print(f"compile cache: {compile_cache.enable()}")
     results = {}
     timings = {}
     t_total = time.time()

@@ -5,11 +5,10 @@ Compares a freshly written BENCH_engine.json against the committed baseline
 (CI snapshots it with `git show HEAD:BENCH_engine.json` before the bench
 runs) and fails when the minimum engine-vs-seed speedup at n_guests >= 8
 falls below TOLERANCE x the baseline's. The tolerance absorbs shared-CI
-wall-clock noise; since every (case, runner) pair now times in its own
-fresh subprocess (benchmarks/bench_engine.py --worker), the cross-runner
-pollution that forced the old 0.8x slack is gone and the gate tightens to
-0.85x. A real regression in the scan-fused driver shows up as a >15% drop
-across every at-scale case.
+wall-clock noise; benchmarks/bench_engine.py times every runner in one
+process (on a TPU no child process could reach the chip). A real regression
+in the scan-fused driver shows up as a >15% drop across every at-scale
+case.
 
 Also gates the steady-state churn engine: `churn_vs_engine` (the fault
 machinery's overhead ratio vs the plain driver) must hold the same

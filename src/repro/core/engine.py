@@ -195,34 +195,36 @@ class EngineSpec:
     def max_hp(self) -> int:
         return max(hi - lo for lo, hi in zip(self.hp_offsets, self.hp_offsets[1:]))
 
-    # ---- segment-offset tables (numpy: trace-time constants) ------------
-    def logical_pad_index(self) -> np.ndarray:
+    # ---- segment-offset tables ---------------------------------------
+    # The two n_logical-sized tables are computed from the per-guest offsets
+    # with jnp (an iota inside jit), never baked in as constants: at a
+    # deployment fleet they would put hundreds of MB into every executable.
+    def logical_pad_index(self) -> jax.Array:
         """int32[n_guests, max_logical]: row g = guest g's global logical ids,
         -1 padded past its segment (the ragged replacement for the old
         ``score.reshape(n_guests, logical_per_guest)``)."""
-        out = np.full((self.n_guests, self.max_logical), -1, np.int32)
-        for g in range(self.n_guests):
-            lo, hi = self.logical_range(g)
-            out[g, : hi - lo] = np.arange(lo, hi, dtype=np.int32)
-        return out
+        lo = np.asarray(self.logical_offsets[:-1], np.int32)[:, None]
+        n = np.diff(np.asarray(self.logical_offsets, np.int32))[:, None]
+        col = jnp.arange(self.max_logical, dtype=jnp.int32)[None, :]
+        return jnp.where(col < n, lo + col, -1)
 
     def hp_pad_index(self) -> np.ndarray:
         """int32[n_guests, max_hp]: row g = guest g's global GPA huge-page
-        ids, -1 padded."""
+        ids, -1 padded (a numpy trace-time constant)."""
         out = np.full((self.n_guests, self.max_hp), -1, np.int32)
         for g in range(self.n_guests):
             lo, hi = self.hp_range(g)
             out[g, : hi - lo] = np.arange(lo, hi, dtype=np.int32)
         return out
 
-    def cl_per_logical(self) -> np.ndarray:
+    def cl_per_logical(self) -> jax.Array:
         """int32[n_logical]: the effective CL of the guest owning each
         logical page (lets one global candidate mask honour per-guest CLs)."""
-        out = np.empty((self.cfg.n_logical,), np.int32)
-        for g in range(self.n_guests):
-            lo, hi = self.logical_range(g)
-            out[lo:hi] = self.guest_cl(g)
-        return out
+        cls = np.asarray(
+            [self.guest_cl(g) for g in range(self.n_guests)], np.int32)
+        starts = np.asarray(self.logical_offsets[1:-1], np.int32)
+        ids = jnp.arange(self.cfg.n_logical, dtype=jnp.int32)
+        return jnp.asarray(cls)[jnp.searchsorted(starts, ids, side="right")]
 
     def localize(self, local_ids: jax.Array) -> jax.Array:
         """Guest-local page ids ``int32[n_guests, k]`` -> combined-space ids

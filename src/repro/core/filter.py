@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.core import telemetry
 from repro.core.types import GpacConfig, TieredState
+from repro.kernels.topk.ref import top_k
 
 
 def candidate_mask(
@@ -68,7 +69,7 @@ def select_batches(
     score = jnp.where(cand, _hotness_score(state), -1)
     k = max_batches * cfg.hp_ratio
     k = min(k, cfg.n_logical)
-    _, top_ids = jax.lax.top_k(score, k)
+    _, top_ids = top_k(score, k)
     top_valid = score[top_ids] >= 0
     ids = jnp.where(top_valid, top_ids.astype(jnp.int32), -1)
     pad = max_batches * cfg.hp_ratio - k

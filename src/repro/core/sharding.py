@@ -56,7 +56,7 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import address_space as asp
@@ -139,7 +139,8 @@ def guest_mesh(n_devices: int | None = None):
             f"{avail} global devices ({jax.process_count()} processes), "
             f"got n_devices={n_devices}"
         )
-    return jax.make_mesh((n_devices,), (AXIS,))
+    return jax.make_mesh(
+        (n_devices,), (AXIS,), axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def mesh_size(mesh) -> int:
@@ -436,9 +437,9 @@ def _chunk_fn(
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P(),
-        # psum results are replicated but 0.4.x rep-checking cannot always
-        # infer it; correctness is pinned by the equivalence tests
-        check_rep=False,
+        # psum results are replicated but the replication check cannot
+        # always infer it; correctness is pinned by the equivalence tests
+        check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -641,7 +642,7 @@ def _churn_chunk_fn(
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -1351,7 +1352,7 @@ def _host_chunk_fn(
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded)
 

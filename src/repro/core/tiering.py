@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from repro.core.address_space import dataclasses_replace
 from repro.core.telemetry import _popcount_u8
 from repro.core.types import GpacConfig, TieredState, allocated_hp_mask
+from repro.kernels.topk.ref import top_k
 
 # builtin names (kept for back-compat; the live set is policies())
 POLICIES = ("memtierd", "autonuma", "tpp")
@@ -138,8 +139,8 @@ def _paired_ids(mask_a, score_a, mask_b, score_b, budget):
     budget = min(budget, mask_a.shape[0])
     sa = jnp.where(mask_a, score_a, NEG)
     sb = jnp.where(mask_b, -score_b, NEG)
-    va, ia = jax.lax.top_k(sa, budget)
-    vb, ib = jax.lax.top_k(sb, budget)
+    va, ia = top_k(sa, budget)
+    vb, ib = top_k(sb, budget)
     ids_a = jnp.where(va > NEG, ia.astype(jnp.int32), -1)
     ids_b = jnp.where(vb > NEG, ib.astype(jnp.int32), -1)
     k = jnp.minimum((ids_a >= 0).sum(), (ids_b >= 0).sum())
@@ -470,7 +471,7 @@ def nominate(
     tie-break by lowest row preserves the replicated tick's id order.
     """
     k = min(b, mask.shape[0])
-    v, i = jax.lax.top_k(jnp.where(mask & (hp_ids >= 0), val, NEG), k)
+    v, i = top_k(jnp.where(mask & (hp_ids >= 0), val, NEG), k)
     ok = v > NEG
     out = dict(
         val=v,

@@ -29,16 +29,10 @@ def _scatter_region_pallas(
     dst_rows: jax.Array, region: jax.Array, ids: jax.Array,
     *, interpret: bool = False,
 ) -> jax.Array:
-    valid = ids >= 0
-    # Padded slots are redirected to row 0 carrying row 0's original data.
-    # Sorting padded-first makes any *real* write to row 0 land last in
-    # the sequential grid, so it wins (writer order = grid order).
-    order = jnp.argsort(valid)
-    clamped = jnp.where(valid, ids, 0).astype(jnp.int32)[order]
-    keep = dst_rows[0]
-    payload = jnp.where(valid[order][:, None], region[order], keep)
-    return _k.consolidate_scatter(dst_rows, payload, clamped,
-                                  interpret=interpret)
+    # the kernel skips out-of-range slots and copies in slot order, so a
+    # repeated destination keeps the last slot's row (the reference's drop
+    # and last-writer semantics)
+    return _k.consolidate_scatter(dst_rows, region, ids, interpret=interpret)
 
 
 def _region_oracle(src_rows, ids):

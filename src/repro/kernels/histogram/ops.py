@@ -51,6 +51,11 @@ registry.register_kernel(
     "bincount", pallas=_bincount_pallas, ref=_ref.bincount_ref,
     oracle=_oracle, example=_example,
     description="weighted bincount (per-window access/host histograms)",
+    tpu_auto="xla",
+    tpu_auto_why=(
+        "O(ids x bins) one-hot: 28.5 ms vs XLA 1.2 ms for 65,536 ids into "
+        "524,289 bins on v5e; the engine's histograms have up to n_logical "
+        "bins (33.5M per chip at the deployment fleet)"),
 )
 
 

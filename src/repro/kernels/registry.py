@@ -15,9 +15,13 @@ knob down to :func:`dispatch`, which resolves the tri-state here:
 * ``"pallas"`` — run the Pallas kernel; interpret mode off-TPU
   (``runtime.interpret()``), native lowering on TPU.
 * ``"auto"``   — honor ``REPRO_KERNEL_BACKEND`` if set (read once at import
-  so jit caches cannot go stale mid-process), else Pallas on TPU and the
-  reference elsewhere — interpretation is slower than XLA on CPU, and the
-  two are bit-identical (INV-KERNEL-BACKEND-EXACT).
+  so jit caches cannot go stale mid-process), else the entry's fixed TPU
+  rule (``KernelSpec.tpu_auto``) on TPU and the reference elsewhere —
+  interpretation is slower than XLA on CPU, and the two are bit-identical
+  (INV-KERNEL-BACKEND-EXACT). An entry whose Pallas form is an order of
+  magnitude or more slower than XLA at the engine's deployment shapes on the
+  chip sets ``tpu_auto="xla"`` and gives the measurement in
+  ``tpu_auto_why``; ``"pallas"`` still runs its kernel natively.
 """
 from __future__ import annotations
 
@@ -46,6 +50,8 @@ class KernelSpec:
     ``oracle`` (optional) is an independent numpy implementation for tests;
     ``example`` (optional) is a nullary callable returning ``(args, kwargs)``
     for generic micro-benchmarks (``benchmarks/bench_kernels.py``).
+    ``tpu_auto`` is what ``"auto"`` runs on a TPU; an entry that sets it to
+    ``"xla"`` states the measured reason in ``tpu_auto_why``.
     """
 
     name: str
@@ -54,6 +60,8 @@ class KernelSpec:
     oracle: Callable | None = None
     example: Callable | None = None
     description: str = ""
+    tpu_auto: str = "pallas"
+    tpu_auto_why: str = ""
 
 
 _KERNELS: dict[str, KernelSpec] = {}
@@ -67,13 +75,21 @@ def register_kernel(
     oracle: Callable | None = None,
     example: Callable | None = None,
     description: str = "",
+    tpu_auto: str = "pallas",
+    tpu_auto_why: str = "",
 ) -> KernelSpec:
     """Register a kernel under a unique name; duplicates raise."""
     if name in _KERNELS:
         raise ValueError(f"kernel {name!r} already registered")
+    if tpu_auto not in ("xla", "pallas") or (tpu_auto == "xla") != bool(
+            tpu_auto_why):
+        raise ValueError(
+            f"kernel {name!r}: tpu_auto must be 'pallas', or 'xla' with a "
+            f"tpu_auto_why, got {tpu_auto!r}")
     spec = KernelSpec(
         name=name, pallas=pallas, ref=ref, oracle=oracle,
         example=example, description=description,
+        tpu_auto=tpu_auto, tpu_auto_why=tpu_auto_why,
     )
     _KERNELS[name] = spec
     return spec
@@ -97,8 +113,10 @@ def all_kernels() -> tuple[KernelSpec, ...]:
     return tuple(_KERNELS[n] for n in kernel_names())
 
 
-def resolve_backend(choice: str = "auto") -> str:
-    """Resolve a backend knob to a concrete ``"xla"`` or ``"pallas"``."""
+def resolve_backend(choice: str = "auto", name: str | None = None) -> str:
+    """Resolve a backend knob to a concrete ``"xla"`` or ``"pallas"``, for
+    kernel ``name`` (whose TPU rule applies under ``"auto"``) or for any
+    kernel without a rule when ``name`` is None."""
     if choice not in BACKENDS:
         raise ValueError(
             f"unknown kernel backend {choice!r} (have {BACKENDS})"
@@ -112,7 +130,14 @@ def resolve_backend(choice: str = "auto") -> str:
                 f"REPRO_KERNEL_BACKEND={env!r} must be 'xla' or 'pallas'"
             )
         return env
-    return "pallas" if runtime.on_tpu() else "xla"
+    if not runtime.on_tpu():
+        return "xla"
+    return get_kernel(name).tpu_auto if name is not None else "pallas"
+
+
+def resolved_backends(choice: str = "auto") -> dict[str, str]:
+    """``{kernel name: backend}`` that ``choice`` runs on this platform."""
+    return {n: resolve_backend(choice, n) for n in kernel_names()}
 
 
 def dispatch(name: str, choice: str, *args, **kwargs):
@@ -124,7 +149,7 @@ def dispatch(name: str, choice: str, *args, **kwargs):
     its own cached executable (the knob rides EngineSpec, a static argument).
     """
     spec = get_kernel(name)
-    resolved = resolve_backend(choice)
+    resolved = resolve_backend(choice, name)
     if resolved == "pallas":
         return spec.pallas(*args, interpret=runtime.interpret(), **kwargs)
     return spec.ref(*args, **kwargs)

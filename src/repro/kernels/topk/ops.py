@@ -36,6 +36,10 @@ registry.register_kernel(
     "topk_rows", pallas=_topk_pallas, ref=_ref.topk_rows_ref,
     oracle=_oracle, example=_example,
     description="row-wise top-k, lax.top_k tie-break (ragged batch filter)",
+    tpu_auto="xla",
+    tpu_auto_why=(
+        "k serial peels over the row: 859 ms vs XLA 36 ms per call at "
+        "8 x 4,194,304, k=2048 on v5e"),
 )
 
 

@@ -46,8 +46,10 @@ def train_mesh(data: int = DEFAULT_DATA, model: int = DEFAULT_MODEL,
             f"train_mesh: axis sizes must be >= 1, got "
             f"data={data}, model={model}, pods={pods}")
     if pods is None:
-        return jax.make_mesh((data, model), ("data", "model"))
-    return jax.make_mesh((pods, data, model), ("pod", "data", "model"))
+        return jax.make_mesh((data, model), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    return jax.make_mesh((pods, data, model), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -215,9 +215,9 @@ class TestConsolidateNoPoolConcat:
                         assert rows < pool_rows, (
                             f"pool-sized concatenate resurfaced: {v.aval.shape}")
                 for v in eqn.params.values():
-                    if isinstance(v, jax.core.ClosedJaxpr):
+                    if isinstance(v, jax.extend.core.ClosedJaxpr):
                         walk(v.jaxpr)
-                    elif isinstance(v, jax.core.Jaxpr):
+                    elif isinstance(v, jax.extend.core.Jaxpr):
                         walk(v)
 
         walk(jaxpr.jaxpr)

@@ -132,6 +132,23 @@ class TestTopK:
         np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
         np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
 
+    @pytest.mark.parametrize(
+        "shape,k", [((4, 64), 8), ((16, 300), 300), ((1, 8), 1), ((2, 4096), 2048),
+                    ((257,), 31)])
+    def test_tie_exact_path_matches_oracle(self, rng, shape, k):
+        """The order rebuilt from values alone (what a TPU runs) keeps
+        lax.top_k's lowest-index tie-break, sentinel scores included."""
+        from repro.kernels.topk import ref as tk_ref
+
+        x = rng.integers(-1, 5, size=shape).astype(np.int32)
+        x.reshape(-1)[:2] = np.iinfo(np.int32).min + 1
+        got_v, got_i = jax.jit(tk_ref._top_k_tie_exact, static_argnums=1)(
+            jnp.asarray(x), k)
+        want_i = np.argsort(-x.astype(np.int64), axis=-1, kind="stable")[..., :k]
+        np.testing.assert_array_equal(np.asarray(got_i), want_i)
+        np.testing.assert_array_equal(
+            np.asarray(got_v), np.take_along_axis(x, want_i, axis=-1))
+
 
 class TestTieredLookup:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
