@@ -41,6 +41,7 @@ from repro.core import address_space as asp
 from repro.core import faults as faults_mod
 from repro.core import gpac, metrics, telemetry, tiering
 from repro.core import tiers as tiers_mod
+from repro.core.scopes import LAYERS, layer  # noqa: F401  (LAYERS re-exported)
 from repro.core.types import GpacConfig, TieredState, allocated_hp_mask, init_state
 from repro.kernels import registry as kernels_registry
 
@@ -653,28 +654,34 @@ def _window(
     guests, one ragged batched GPAC pass, one host tier tick, window roll,
     then the requested collectors."""
     cfg = spec.cfg
-    ids = spec.localize(accesses)
-    slot, _, valid = asp.translate(cfg, state, ids)
-    window = dict(
-        near_hits=(valid & (slot < cfg.n_near)).sum(axis=1),
-        far_hits=(valid & (slot >= cfg.n_near)).sum(axis=1),
-    )
+    with layer("tier.translate"):
+        ids = spec.localize(accesses)
+        slot, _, valid = asp.translate(cfg, state, ids)
+        window = dict(
+            near_hits=(valid & (slot < cfg.n_near)).sum(axis=1),
+            far_hits=(valid & (slot >= cfg.n_near)).sum(axis=1),
+        )
     if "tco" in collect:
-        window["tier_hits"] = tiers_mod.tier_hit_counts(
-            spec.tier_vector, slot, valid)
-    state = asp.record_accesses(
-        cfg, state, ids.reshape(-1), kernel_backend=spec.kernel_backend)
+        with layer("tier.roll"):
+            window["tier_hits"] = tiers_mod.tier_hit_counts(
+                spec.tier_vector, slot, valid)
+    with layer("tier.record"):
+        state = asp.record_accesses(
+            cfg, state, ids.reshape(-1), kernel_backend=spec.kernel_backend)
     if use_gpac:
         # all N guest daemons in one batched pass over the segment-offset
         # tables; disjoint segments make this bit-equal to N sequential
-        # per-guest gpac_maintenance calls (see run_reference)
+        # per-guest gpac_maintenance calls (see run_reference); the pass
+        # scopes its own layers
         state = gpac.gpac_maintenance_ragged(spec, state, backend, max_batches)
-    state = tiering.strided_tick(
-        cfg, state, policy, stride=spec.arbitration_stride, budget=budget,
-        tiers=spec.tiers,
-    )
-    state = telemetry.end_window(cfg, state)
-    return state, run_collectors(spec, state, window, collect)
+    with layer("tier.tick"):
+        state = tiering.strided_tick(
+            cfg, state, policy, stride=spec.arbitration_stride, budget=budget,
+            tiers=spec.tiers,
+        )
+    with layer("tier.roll"):
+        state = telemetry.end_window(cfg, state)
+        return state, run_collectors(spec, state, window, collect)
 
 
 @partial(
@@ -853,13 +860,18 @@ def _drive_chunks(
     n_w = by_window.shape[0]
     chunks = []
     for s in range(0, n_w, wps):
-        state, out = chunk_fn(state, jnp.asarray(by_window[s : s + wps]))
+        with jax.profiler.TraceAnnotation("tier.prepare"):
+            chunk = jnp.asarray(by_window[s : s + wps])
+        with jax.profiler.TraceAnnotation("tier.dispatch"):
+            state, out = chunk_fn(state, chunk)
         chunks.append(out)
     if not collect:
         return state, {}
-    series = {
-        k: np.concatenate([np.asarray(c[k]) for c in chunks]) for k in chunks[0]
-    }
+    with jax.profiler.TraceAnnotation("tier.fetch"):
+        series = {
+            k: np.concatenate([np.asarray(c[k]) for c in chunks])
+            for k in chunks[0]
+        }
     return state, series
 
 
@@ -930,7 +942,8 @@ def run(
     shape ``[n_windows, ...]`` per collector output; empty dict when the
     source has no windows or ``collect`` is empty.
     """
-    source = _coerce_source(source, traces)
+    with jax.profiler.TraceAnnotation("tier.prepare"):
+        source = _coerce_source(source, traces)
     spec = _with_kernel_backend(spec, kernel_backend)
     spec = _with_arbitration_stride(spec, arbitration_stride)
     collect = _validate_run_args(spec, source, collect)
@@ -950,8 +963,9 @@ def run(
             )
     else:
         spec = spec.canonical()  # don't recompile across seed/workload sweeps
-        by_window = np.ascontiguousarray(
-            np.transpose(source.traces, (1, 0, 2)))
+        with jax.profiler.TraceAnnotation("tier.prepare"):
+            by_window = np.ascontiguousarray(
+                np.transpose(source.traces, (1, 0, 2)))
 
         def chunk_fn(st, chunk):
             return _run_chunk(
@@ -1255,33 +1269,38 @@ def _churn_window(
         spec, cs.state, cs.active, frow["crash"], frow["restart"]
     )
     near_cap = jnp.minimum(frow["near_cap"], jnp.int32(cfg.n_near))
-    acc = jnp.where(active[:, None], accesses, -1)
-    ids = spec.localize(acc)
-    slot, _, valid = asp.translate(cfg, state, ids)
-    window = dict(
-        near_hits=(valid & (slot < cfg.n_near)).sum(axis=1),
-        far_hits=(valid & (slot >= cfg.n_near)).sum(axis=1),
-    )
+    with layer("tier.translate"):
+        acc = jnp.where(active[:, None], accesses, -1)
+        ids = spec.localize(acc)
+        slot, _, valid = asp.translate(cfg, state, ids)
+        window = dict(
+            near_hits=(valid & (slot < cfg.n_near)).sum(axis=1),
+            far_hits=(valid & (slot >= cfg.n_near)).sum(axis=1),
+        )
     if "tco" in collect:
-        window["tier_hits"] = tiers_mod.tier_hit_counts(
-            spec.tier_vector, slot, valid)
-    keep = jnp.where(frow["drop"], 0, 1).astype(jnp.int32)
+        with layer("tier.roll"):
+            window["tier_hits"] = tiers_mod.tier_hit_counts(
+                spec.tier_vector, slot, valid)
     kb = spec.kernel_backend
-    state = asp.apply_access_histogram(
-        cfg, state, asp.access_histogram(cfg, ids, valid, kb) * keep, kb
-    )
+    with layer("tier.record"):
+        keep = jnp.where(frow["drop"], 0, 1).astype(jnp.int32)
+        state = asp.apply_access_histogram(
+            cfg, state, asp.access_histogram(cfg, ids, valid, kb) * keep, kb
+        )
     if use_gpac:
         state = gpac.gpac_maintenance_ragged(spec, state, backend, max_batches)
-    state = tiering.strided_tick(
-        cfg, state, policy, stride=spec.arbitration_stride, budget=budget,
-        tiers=spec.tiers,
-    )
-    state, engaged, press = tiering.pressure_tick(
-        cfg, state, near_cap, cs.engaged, cs.pressure,
-        budget=budget, slack=slack, tiers=spec.tiers,
-    )
-    state = telemetry.end_window(cfg, state)
-    out = run_collectors(spec, state, window, collect)
+    with layer("tier.tick"):
+        state = tiering.strided_tick(
+            cfg, state, policy, stride=spec.arbitration_stride, budget=budget,
+            tiers=spec.tiers,
+        )
+        state, engaged, press = tiering.pressure_tick(
+            cfg, state, near_cap, cs.engaged, cs.pressure,
+            budget=budget, slack=slack, tiers=spec.tiers,
+        )
+    with layer("tier.roll"):
+        state = telemetry.end_window(cfg, state)
+        out = run_collectors(spec, state, window, collect)
     clash = set(out) & set(_CHURN_SERIES)
     if clash:
         raise ValueError(

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.core import address_space as asp
 from repro.core import consolidator, filter as pfilter, telemetry, tiering
+from repro.core.scopes import layer
 from repro.core.types import GpacConfig, TieredState
 
 
@@ -63,13 +64,19 @@ def gpac_maintenance_ragged(
     reproduce N sequential :func:`gpac_maintenance` calls bit-for-bit -- with
     O(1) trace cost and ~n_guests x less classification/sort work."""
     cfg = spec.cfg
+    # the segment tables are computed in the program: each in the layer
+    # that reads it
+    with layer("tier.score"):
+        cl_per_logical = jnp.asarray(spec.cl_per_logical())
+    with layer("tier.select"):
+        pad_idx = jnp.asarray(spec.logical_pad_index())
     return gpac_maintenance_rows(
         cfg,
         state,
         backend,
         max_batches,
-        jnp.asarray(spec.cl_per_logical()),
-        jnp.asarray(spec.logical_pad_index()),
+        cl_per_logical,
+        pad_idx,
         jnp.asarray(spec.hp_pad_index()),
         spec.kernel_backend,
     )
@@ -96,13 +103,18 @@ def gpac_maintenance_rows(
     all-guests call (:func:`gpac_maintenance_ragged`) passes every row; the
     device-sharded engine passes only the rows a device owns -- segments are
     disjoint, so each device's pass *writes* disjoint state and the shard
-    merge is exact."""
-    hot = telemetry.hot_mask(cfg, state, backend)
-    score = pfilter.candidate_score(cfg, state, hot, cl_per_logical, kernel_backend)
-    batches = pfilter.select_batches_from_rows(
-        cfg, score, pad_idx, max_batches, kernel_backend)
-    return consolidator.consolidate_rounds(
-        cfg, state, batches, hp_pad_idx, kernel_backend)
+    merge is exact. Each stage runs in its engine layer scope."""
+    with layer("tier.classify"):
+        hot = telemetry.hot_mask(cfg, state, backend)
+    with layer("tier.score"):
+        score = pfilter.candidate_score(
+            cfg, state, hot, cl_per_logical, kernel_backend)
+    with layer("tier.select"):
+        batches = pfilter.select_batches_from_rows(
+            cfg, score, pad_idx, max_batches, kernel_backend)
+    with layer("tier.copy"):
+        return consolidator.consolidate_rounds(
+            cfg, state, batches, hp_pad_idx, kernel_backend)
 
 
 def gpac_maintenance_batched(

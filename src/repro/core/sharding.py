@@ -63,6 +63,7 @@ from repro.core import address_space as asp
 from repro.core import faults as faults_mod
 from repro.core import gpac, telemetry, tiering
 from repro.core import tiers as tiers_mod
+from repro.core.scopes import layer
 from repro.core.types import FREE, GpacConfig, TieredState
 
 AXIS = "guest"
@@ -319,19 +320,23 @@ def _sharded_window(
     cfg = spec.cfg
     base = state
     # ---- 1. access phase (sharded, applied locally) ----------------------
-    ids = jnp.where(accesses >= 0, accesses + logical_lo[:, None], -1)
-    slot, _, valid = asp.translate(cfg, state, ids)
-    near_loc = (valid & (slot < cfg.n_near)).sum(axis=1)
-    far_loc = (valid & (slot >= cfg.n_near)).sum(axis=1)
+    with layer("tier.translate"):
+        ids = jnp.where(accesses >= 0, accesses + logical_lo[:, None], -1)
+        slot, _, valid = asp.translate(cfg, state, ids)
+        near_loc = (valid & (slot < cfg.n_near)).sum(axis=1)
+        far_loc = (valid & (slot >= cfg.n_near)).sum(axis=1)
     kb = spec.kernel_backend
-    local = asp.apply_access_histogram(
-        cfg, state, asp.access_histogram(cfg, ids, valid, kb), kb
-    )
+    with layer("tier.record"):
+        local = asp.apply_access_histogram(
+            cfg, state, asp.access_histogram(cfg, ids, valid, kb), kb
+        )
     # ---- 2. GPAC phase (sharded: this device's segment rows only) --------
     if use_gpac:
+        with layer("tier.score"):
+            cl_per_logical = jnp.asarray(spec.cl_per_logical())
         local = gpac.gpac_maintenance_rows(
-            cfg, local, backend, max_batches,
-            jnp.asarray(spec.cl_per_logical()), logical_pad, hp_pad, kb,
+            cfg, local, backend, max_batches, cl_per_logical, logical_pad,
+            hp_pad, kb,
         )
     # ---- 3. one-collective ownership merge -------------------------------
     extras = [
@@ -340,26 +345,29 @@ def _sharded_window(
     if "tco" in collect:
         # local per-tier hit vector; the psum of int counts reproduces the
         # replicated tier_hit_counts exactly
-        extras.append(
-            tiers_mod.tier_hit_counts(spec.tier_vector, slot, valid))
+        with layer("tier.roll"):
+            extras.append(
+                tiers_mod.tier_hit_counts(spec.tier_vector, slot, valid))
     state, merged_extras = merge_window(
         cfg, base, local, logical_pad, hp_pad, tuple(extras),
         merged_gpac=use_gpac,
     )
     near_all, far_all = merged_extras[0], merged_extras[1]
     # ---- 4. host tick + window roll (replicated) ------------------------
-    state = tiering.strided_tick(
-        cfg, state, policy, stride=spec.arbitration_stride, budget=budget,
-        tiers=spec.tiers,
-    )
-    state = telemetry.end_window(cfg, state)
-    window = dict(
-        near_hits=near_all[: spec.n_guests],
-        far_hits=far_all[: spec.n_guests],
-    )
-    if "tco" in collect:
-        window["tier_hits"] = merged_extras[2]
-    return state, run_collectors(spec, state, window, collect)
+    with layer("tier.tick"):
+        state = tiering.strided_tick(
+            cfg, state, policy, stride=spec.arbitration_stride, budget=budget,
+            tiers=spec.tiers,
+        )
+    with layer("tier.roll"):
+        state = telemetry.end_window(cfg, state)
+        window = dict(
+            near_hits=near_all[: spec.n_guests],
+            far_hits=far_all[: spec.n_guests],
+        )
+        if "tco" in collect:
+            window["tier_hits"] = merged_extras[2]
+        return state, run_collectors(spec, state, window, collect)
 
 
 # per-guest synthesis-table keys, in the order the chunk drivers append
@@ -515,51 +523,59 @@ def _churn_sharded_window(
     near_cap = jnp.minimum(frow["near_cap"], jnp.int32(cfg.n_near))
     base = state
     # ---- 1. access phase (sharded; inactive + padding lanes emit -1) -----
-    g_loc = accesses.shape[0]
-    pos = jax.lax.axis_index(AXIS) * g_loc + jnp.arange(g_loc)
-    act_loc = jnp.where(pos < n_g, active[jnp.minimum(pos, n_g - 1)], False)
-    acc = jnp.where(act_loc[:, None], accesses, -1)
-    ids = jnp.where(acc >= 0, acc + logical_lo[:, None], -1)
-    slot, _, valid = asp.translate(cfg, state, ids)
-    near_loc = (valid & (slot < cfg.n_near)).sum(axis=1)
-    far_loc = (valid & (slot >= cfg.n_near)).sum(axis=1)
-    keep = jnp.where(frow["drop"], 0, 1).astype(jnp.int32)
+    with layer("tier.translate"):
+        g_loc = accesses.shape[0]
+        pos = jax.lax.axis_index(AXIS) * g_loc + jnp.arange(g_loc)
+        act_loc = jnp.where(
+            pos < n_g, active[jnp.minimum(pos, n_g - 1)], False)
+        acc = jnp.where(act_loc[:, None], accesses, -1)
+        ids = jnp.where(acc >= 0, acc + logical_lo[:, None], -1)
+        slot, _, valid = asp.translate(cfg, state, ids)
+        near_loc = (valid & (slot < cfg.n_near)).sum(axis=1)
+        far_loc = (valid & (slot >= cfg.n_near)).sum(axis=1)
     kb = spec.kernel_backend
-    local = asp.apply_access_histogram(
-        cfg, state, asp.access_histogram(cfg, ids, valid, kb) * keep, kb
-    )
+    with layer("tier.record"):
+        keep = jnp.where(frow["drop"], 0, 1).astype(jnp.int32)
+        local = asp.apply_access_histogram(
+            cfg, state, asp.access_histogram(cfg, ids, valid, kb) * keep, kb
+        )
     # ---- 2. GPAC phase (sharded: this device's segment rows only) --------
     if use_gpac:
+        with layer("tier.score"):
+            cl_per_logical = jnp.asarray(spec.cl_per_logical())
         local = gpac.gpac_maintenance_rows(
-            cfg, local, backend, max_batches,
-            jnp.asarray(spec.cl_per_logical()), logical_pad, hp_pad, kb,
+            cfg, local, backend, max_batches, cl_per_logical, logical_pad,
+            hp_pad, kb,
         )
     # ---- 3. one-collective ownership merge -------------------------------
     extras = [
         _spread_rows(near_loc, n_shards), _spread_rows(far_loc, n_shards),
     ]
     if "tco" in collect:
-        extras.append(
-            tiers_mod.tier_hit_counts(spec.tier_vector, slot, valid))
+        with layer("tier.roll"):
+            extras.append(
+                tiers_mod.tier_hit_counts(spec.tier_vector, slot, valid))
     state, merged_extras = merge_window(
         cfg, base, local, logical_pad, hp_pad, tuple(extras),
         merged_gpac=use_gpac,
     )
     near_all, far_all = merged_extras[0], merged_extras[1]
     # ---- 4. host + pressure ticks, window roll (replicated) --------------
-    state = tiering.strided_tick(
-        cfg, state, policy, stride=spec.arbitration_stride, budget=budget,
-        tiers=spec.tiers,
-    )
-    state, engaged, press = tiering.pressure_tick(
-        cfg, state, near_cap, cs.engaged, cs.pressure,
-        budget=budget, slack=slack, tiers=spec.tiers,
-    )
-    state = telemetry.end_window(cfg, state)
-    window = dict(near_hits=near_all[:n_g], far_hits=far_all[:n_g])
-    if "tco" in collect:
-        window["tier_hits"] = merged_extras[2]
-    out = run_collectors(spec, state, window, collect)
+    with layer("tier.tick"):
+        state = tiering.strided_tick(
+            cfg, state, policy, stride=spec.arbitration_stride, budget=budget,
+            tiers=spec.tiers,
+        )
+        state, engaged, press = tiering.pressure_tick(
+            cfg, state, near_cap, cs.engaged, cs.pressure,
+            budget=budget, slack=slack, tiers=spec.tiers,
+        )
+    with layer("tier.roll"):
+        state = telemetry.end_window(cfg, state)
+        window = dict(near_hits=near_all[:n_g], far_hits=far_all[:n_g])
+        if "tco" in collect:
+            window["tier_hits"] = merged_extras[2]
+        out = run_collectors(spec, state, window, collect)
     clash = set(out) & set(_CHURN_SERIES)
     if clash:
         raise ValueError(
@@ -1004,41 +1020,53 @@ def _host_sharded_group(
         stats0 = dict(stats)
         accesses = accs[j]
         # ---- 1. access phase (local: own guests touch own blocks) -------
-        ids = jnp.where(accesses >= 0, accesses + logical_lo[:, None], -1)
-        valid = (ids >= 0) & (ids < cfg.n_logical)
-        hp = gpt[jnp.where(valid, ids, 0)] // cfg.hp_ratio
-        bt_view = _spread_hp(
-            loc["bt"], hp_ids, cfg.n_gpa_hp, jnp.int32(cfg.n_gpa_hp))
-        slot = bt_view[hp]
-        near_loc = (valid & (slot < cfg.n_near)).sum(axis=1).astype(jnp.int32)
-        far_loc = (valid & (slot >= cfg.n_near)).sum(axis=1).astype(jnp.int32)
-        h = asp.access_histogram(cfg, ids, valid, kb)
-        gc = gc + h
-        inc_full = asp.host_histogram(cfg, gpt, h, kb)
-        inc_loc = jnp.where(hp_ids >= 0, inc_full[jnp.maximum(hp_ids, 0)], 0)
-        loc["hc"] = loc["hc"] + inc_loc
-        loc["lt"] = jnp.where(
-            inc_loc > 0, jnp.maximum(loc["lt"], epoch), loc["lt"])
-        stats["near_hits"] = stats["near_hits"] + near_loc.sum()
-        stats["far_hits"] = stats["far_hits"] + far_loc.sum()
+        with layer("tier.translate"):
+            ids = jnp.where(accesses >= 0, accesses + logical_lo[:, None], -1)
+            valid = (ids >= 0) & (ids < cfg.n_logical)
+            hp = gpt[jnp.where(valid, ids, 0)] // cfg.hp_ratio
+            bt_view = _spread_hp(
+                loc["bt"], hp_ids, cfg.n_gpa_hp, jnp.int32(cfg.n_gpa_hp))
+            slot = bt_view[hp]
+            near_loc = (
+                (valid & (slot < cfg.n_near)).sum(axis=1).astype(jnp.int32))
+            far_loc = (
+                (valid & (slot >= cfg.n_near)).sum(axis=1).astype(jnp.int32))
+        with layer("tier.record"):
+            h = asp.access_histogram(cfg, ids, valid, kb)
+            gc = gc + h
+            inc_full = asp.host_histogram(cfg, gpt, h, kb)
+            inc_loc = jnp.where(
+                hp_ids >= 0, inc_full[jnp.maximum(hp_ids, 0)], 0)
+            loc["hc"] = loc["hc"] + inc_loc
+            loc["lt"] = jnp.where(
+                inc_loc > 0, jnp.maximum(loc["lt"], epoch), loc["lt"])
+        with layer("tier.translate"):
+            stats["near_hits"] = stats["near_hits"] + near_loc.sum()
+            stats["far_hits"] = stats["far_hits"] + far_loc.sum()
 
         # ---- 2. GPAC phase (own segment rows, hp-owned payload) ---------
         if use_gpac:
-            re_view = _spread_hp(loc["re"], hp_ids, cfg.n_gpa_hp, jnp.int32(-1))
-            view = _view_state(cfg, gpt, rmap, gc, ih, re_view, epoch, stats)
-            hot = telemetry.hot_mask(cfg, view, backend)
-            score = pfilter.candidate_score(
-                cfg, view, hot, jnp.asarray(spec.cl_per_logical()), kb
-            )
-            batches = pfilter.select_batches_from_rows(
-                cfg, score, logical_pad, max_batches, kb
-            )
-            gpt, rmap, loc["data"], loc["re"], stats = (
-                consolidator.consolidate_rounds_local(
-                    cfg, gpt, rmap, loc["data"], loc["re"], epoch, stats,
-                    batches, hp_pad, hp_lo, kb,
+            with layer("tier.classify"):
+                re_view = _spread_hp(
+                    loc["re"], hp_ids, cfg.n_gpa_hp, jnp.int32(-1))
+                view = _view_state(
+                    cfg, gpt, rmap, gc, ih, re_view, epoch, stats)
+                hot = telemetry.hot_mask(cfg, view, backend)
+            with layer("tier.score"):
+                score = pfilter.candidate_score(
+                    cfg, view, hot, jnp.asarray(spec.cl_per_logical()), kb
                 )
-            )
+            with layer("tier.select"):
+                batches = pfilter.select_batches_from_rows(
+                    cfg, score, logical_pad, max_batches, kb
+                )
+            with layer("tier.copy"):
+                gpt, rmap, loc["data"], loc["re"], stats = (
+                    consolidator.consolidate_rounds_local(
+                        cfg, gpt, rmap, loc["data"], loc["re"], epoch, stats,
+                        batches, hp_pad, hp_lo, kb,
+                    )
+                )
 
         # ---- 3a. this window's share of the group collective ------------
         # local per-tier access and pre-tick block counts ride the group
@@ -1048,46 +1076,53 @@ def _host_sharded_group(
         # likewise: this device's window stat deltas (access + GPAC phases;
         # the tick's are replicated and added after arbitration) and its
         # local allocated / allocated-near block counts.
-        alloc_full = (
-            rmap.reshape(cfg.n_gpa_hp, cfg.hp_ratio) != FREE).any(axis=1)
-        alloc_loc = jnp.where(
-            hp_ids >= 0, alloc_full[jnp.maximum(hp_ids, 0)], False)
-        contrib = dict(
-            near=_spread_rows(near_loc, n_shards),
-            far=_spread_rows(far_loc, n_shards),
-        )
-        if "near_blocks" in collect:
-            contrib["near_blocks"] = _spread_rows(
-                _near_blocks_local(cfg, alloc_loc, loc["bt"], hp_lo, hp_pad),
-                n_shards,
+        with layer("tier.roll"):
+            alloc_full = (
+                rmap.reshape(cfg.n_gpa_hp, cfg.hp_ratio) != FREE).any(axis=1)
+            alloc_loc = jnp.where(
+                hp_ids >= 0, alloc_full[jnp.maximum(hp_ids, 0)], False)
+            contrib = dict(
+                near=_spread_rows(near_loc, n_shards),
+                far=_spread_rows(far_loc, n_shards),
             )
-        if "tco" in collect:
-            contrib["tier_hits"] = tiers_mod.tier_hit_counts(tv, slot, valid)
-            contrib["tier_blocks"] = tiers_mod.tier_block_counts(
-                tv, loc["bt"], alloc_loc)
-        if gstats is not None:
-            contrib["stat_delta"] = {k: stats[k] - stats0[k] for k in stats}
-            contrib["alloc_near"] = (
-                alloc_loc & (loc["bt"] < cfg.n_near)).sum()
-            contrib["alloc_tot"] = alloc_loc.sum()
+            if "near_blocks" in collect:
+                contrib["near_blocks"] = _spread_rows(
+                    _near_blocks_local(
+                        cfg, alloc_loc, loc["bt"], hp_lo, hp_pad),
+                    n_shards,
+                )
+            if "tco" in collect:
+                contrib["tier_hits"] = tiers_mod.tier_hit_counts(
+                    tv, slot, valid)
+                contrib["tier_blocks"] = tiers_mod.tier_block_counts(
+                    tv, loc["bt"], alloc_loc)
+            if gstats is not None:
+                contrib["stat_delta"] = {
+                    k: stats[k] - stats0[k] for k in stats}
+                contrib["alloc_near"] = (
+                    alloc_loc & (loc["bt"] < cfg.n_near)).sum()
+                contrib["alloc_tot"] = alloc_loc.sum()
         per_win.append(contrib)
 
         if j < stride - 1:
             # tick-less window roll: arbitration waits for the group's last
             # window, telemetry keeps accumulating across the stride
-            ih = ((ih << 1) | (gc > 0).astype(jnp.uint8)).astype(jnp.uint8)
-            loc["hh"] = ((loc["hh"] << 1)
-                         | (loc["hc"] > 0).astype(jnp.uint8)).astype(jnp.uint8)
-            gc = jnp.zeros_like(gc)
-            loc["hc"] = jnp.zeros_like(loc["hc"])
-            epoch = epoch + 1
+            with layer("tier.roll"):
+                ih = ((ih << 1) | (gc > 0).astype(jnp.uint8)).astype(jnp.uint8)
+                loc["hh"] = (
+                    (loc["hh"] << 1) | (loc["hc"] > 0).astype(jnp.uint8)
+                ).astype(jnp.uint8)
+                gc = jnp.zeros_like(gc)
+                loc["hc"] = jnp.zeros_like(loc["hc"])
+                epoch = epoch + 1
         else:
             # ---- 3b. nominate on the stride's accumulated telemetry -----
             L = dict(
                 hp_ids=hp_ids, hp_lo=hp_lo, hp_hi=hp_hi, bt=loc["bt"],
                 hc=loc["hc"], hh=loc["hh"], lt=loc["lt"], alloc=alloc_loc,
             )
-            payload = prepare(cfg, L, budget)
+            with layer("tier.tick"):
+                payload = prepare(cfg, L, budget)
 
     # ---- 3c. the group's single collective ------------------------------
     exchange = dict(
@@ -1105,75 +1140,80 @@ def _host_sharded_group(
     mwin = merged["win"]
 
     # ---- 4. arbitration: replicated decisions, local block-table writes -
-    loc["bt"], tick_stats, swaps = apply(
-        cfg, L, dict(cands=merged["cands"], sums=merged["sums"]), budget
-    )
-    on_d0 = jax.lax.axis_index(AXIS) == 0
-    for s in tick_stats:  # replicated deltas: count them on one device only
-        stats[s] = stats[s] + jnp.where(on_d0, tick_stats[s], 0)
+    with layer("tier.tick"):
+        loc["bt"], tick_stats, swaps = apply(
+            cfg, L, dict(cands=merged["cands"], sums=merged["sums"]), budget
+        )
+        on_d0 = jax.lax.axis_index(AXIS) == 0
+        for s in tick_stats:  # replicated deltas: count them on one device
+            stats[s] = stats[s] + jnp.where(on_d0, tick_stats[s], 0)
 
     # ---- 5. last window's roll (telemetry.end_window, by residency) -----
-    ih = ((ih << 1) | (gc > 0).astype(jnp.uint8)).astype(jnp.uint8)
-    loc["hh"] = ((loc["hh"] << 1)
-                 | (loc["hc"] > 0).astype(jnp.uint8)).astype(jnp.uint8)
-    gc = jnp.zeros_like(gc)
-    loc["hc"] = jnp.zeros_like(loc["hc"])
-    epoch = epoch + 1
+    with layer("tier.roll"):
+        ih = ((ih << 1) | (gc > 0).astype(jnp.uint8)).astype(jnp.uint8)
+        loc["hh"] = ((loc["hh"] << 1)
+                     | (loc["hc"] > 0).astype(jnp.uint8)).astype(jnp.uint8)
+        gc = jnp.zeros_like(gc)
+        loc["hc"] = jnp.zeros_like(loc["hc"])
+        epoch = epoch + 1
 
     # ---- 6. per-window collector outputs, stacked [stride, ...] ---------
-    n_g = spec.n_guests
-    emits = []
-    for j in range(stride):
-        last = j == stride - 1
-        out_j = {}
-        for name in collect:
-            if name == "hits":
-                emitted = dict(
-                    near_hits=mwin["near"][j][:n_g],
-                    far_hits=mwin["far"][j][:n_g],
-                )
-            elif name == "near_blocks":
-                pre = mwin["near_blocks"][j]
-                if last:
-                    pre = pre + _near_blocks_delta(spec, swaps, pre.shape[0])
-                emitted = dict(near_blocks=pre[:n_g])
-            elif name == "snapshot":
-                # metrics.device_snapshot reconstructed from the exchange:
-                # same int sums -> bit-identical float divisions
-                gstats = {
-                    k: gstats[k] + mwin["stat_delta"][k][j]
-                    + (tick_stats.get(k, 0) if last else 0)
-                    for k in gstats
-                }
-                alloc_near = mwin["alloc_near"][j] + (
-                    _near_scalar_delta(cfg, swaps) if last else 0)
-                rss = jnp.maximum(mwin["alloc_tot"][j], 1)
-                emitted = dict(
-                    epoch=epoch_in + j + 1,
-                    near_usage=alloc_near / rss,
-                    near_capacity_used=alloc_near / cfg.n_near,
-                    hit_rate=gstats["near_hits"] / jnp.maximum(
-                        gstats["near_hits"] + gstats["far_hits"], 1),
-                    **gstats,
-                )
-            elif name == "tco":
-                blocks = mwin["tier_blocks"][j]
-                if last:
-                    blocks = blocks + tiers_mod.tier_count_delta(tv, swaps)
-                emitted = tiers_mod.tco_metrics(cfg, tv, blocks,
-                                                mwin["tier_hits"][j])
-            else:  # pragma: no cover - engine.run_sharded validates upfront
-                raise ValueError(
-                    f"collector {name!r} has no host-sharded form")
-            clash = set(emitted) & set(out_j)
-            if clash:
-                raise ValueError(
-                    f"collector {name!r} emits keys {sorted(clash)} already "
-                    f"produced by an earlier collector in {collect}"
-                )
-            out_j.update(emitted)
-        emits.append(out_j)
-    out = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *emits)
+    with layer("tier.roll"):
+        n_g = spec.n_guests
+        emits = []
+        for j in range(stride):
+            last = j == stride - 1
+            out_j = {}
+            for name in collect:
+                if name == "hits":
+                    emitted = dict(
+                        near_hits=mwin["near"][j][:n_g],
+                        far_hits=mwin["far"][j][:n_g],
+                    )
+                elif name == "near_blocks":
+                    pre = mwin["near_blocks"][j]
+                    if last:
+                        pre = pre + _near_blocks_delta(
+                            spec, swaps, pre.shape[0])
+                    emitted = dict(near_blocks=pre[:n_g])
+                elif name == "snapshot":
+                    # metrics.device_snapshot reconstructed from the exchange:
+                    # same int sums -> bit-identical float divisions
+                    gstats = {
+                        k: gstats[k] + mwin["stat_delta"][k][j]
+                        + (tick_stats.get(k, 0) if last else 0)
+                        for k in gstats
+                    }
+                    alloc_near = mwin["alloc_near"][j] + (
+                        _near_scalar_delta(cfg, swaps) if last else 0)
+                    rss = jnp.maximum(mwin["alloc_tot"][j], 1)
+                    emitted = dict(
+                        epoch=epoch_in + j + 1,
+                        near_usage=alloc_near / rss,
+                        near_capacity_used=alloc_near / cfg.n_near,
+                        hit_rate=gstats["near_hits"] / jnp.maximum(
+                            gstats["near_hits"] + gstats["far_hits"], 1),
+                        **gstats,
+                    )
+                elif name == "tco":
+                    blocks = mwin["tier_blocks"][j]
+                    if last:
+                        blocks = blocks + tiers_mod.tier_count_delta(tv, swaps)
+                    emitted = tiers_mod.tco_metrics(cfg, tv, blocks,
+                                                    mwin["tier_hits"][j])
+                else:  # pragma: no cover - run_sharded validates upfront
+                    raise ValueError(
+                        f"collector {name!r} has no host-sharded form")
+                clash = set(emitted) & set(out_j)
+                if clash:
+                    raise ValueError(
+                        f"collector {name!r} emits keys {sorted(clash)} "
+                        f"already produced by an earlier collector in "
+                        f"{collect}"
+                    )
+                out_j.update(emitted)
+            emits.append(out_j)
+        out = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *emits)
 
     new_carry = dict(
         gpt=gpt, rmap=rmap, guest_counts=gc, ipt_hist=ih, epoch=epoch,
