@@ -36,15 +36,20 @@ def candidate_mask(
 ) -> jax.Array:
     """bool[n_logical]: hot pages living in skewed (< CL hot subpages) huge
     pages that are not inside a cooldown region. ``allow`` optionally
-    restricts candidates to one guest's logical pages (multi-tenant)."""
+    restricts candidates to one guest's logical pages (multi-tenant).
+
+    Cooling is folded into the per-huge-page hot count (a cooling region
+    reads 0) before the single per-page lookup, since a cooling or empty
+    huge page is never a candidate: one gather of ``n_logical`` indices
+    instead of two."""
     cl = cfg.cl if cl is None else cl
     per_hp = telemetry.hot_subpages_per_hp(cfg, state, hot, kernel_backend)
-    hp_of = state.gpt // cfg.hp_ratio
-    skewed = (per_hp[hp_of] > 0) & (per_hp[hp_of] < cl)
-    cooling = (state.region_epoch[hp_of] >= 0) & (
-        state.epoch - state.region_epoch[hp_of] < cfg.reconsolidate_cooldown
+    cooling = (state.region_epoch >= 0) & (
+        state.epoch - state.region_epoch < cfg.reconsolidate_cooldown
     )
-    out = hot & skewed & ~cooling
+    per_hp = jnp.where(cooling, 0, per_hp)
+    n_hot = per_hp[state.gpt // cfg.hp_ratio]
+    out = hot & (n_hot > 0) & (n_hot < cl)
     if allow is not None:
         out = out & allow
     return out
