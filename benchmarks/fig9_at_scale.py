@@ -198,10 +198,9 @@ def run_pod(n_guests: int = POD_GUESTS,
         name = "fig9_at_pod_scale"
     coll = sharding.collective_bytes()
     # exact per-psum payload bytes, recorded at trace time; merge_window /
-    # host_exchange fire once per window (stride 1), host_chunk_exit once
-    # per scan chunk
+    # host_exchange fire once per window (stride 1), host_gather once per
+    # run (run_series hands run_sharded a replicated state)
     per_window = coll.get("merge_window", 0) + coll.get("host_exchange", 0)
-    n_chunks = -(-n_windows // max(1, n_windows // 2))
     out = {
         policy: res,
         "n_guests": n_guests,
@@ -212,7 +211,7 @@ def run_pod(n_guests: int = POD_GUESTS,
             per_site_bytes=coll,
             per_window_bytes=per_window,
             bytes_per_run=per_window * n_windows
-            + coll.get("host_chunk_exit", 0) * n_chunks,
+            + coll.get("host_gather", 0),
         ),
         # no [n_guests, n_windows, k] array exists anywhere on this path
         "synth_trace_bytes_per_device_window":

@@ -17,10 +17,10 @@ of 65,536 accesses per guest through ``engine.run`` (SynthTrace source,
     ``engine.run_reference`` bit for bit on an ``ArrayTrace``;
 (c) printed, not gating: hit rate and near blocks held with GPAC and without.
 
-Four chips: ``engine.run_sharded(host_sharded=True)`` on a 4-chip ``"guest"``
-mesh over a larger fleet, the contracts' state invariants on its result, the
-one-chip fleet on 4 chips vs 1 device (bit-identical), one ``run_churn`` plus
-``migration.migrate_guest`` on the mesh, and per-device memory.
+Four chips: one ``run_churn`` plus ``migration.migrate_guest`` on a 4-chip
+``"guest"`` mesh. The host-partitioned engine on four chips is the chip
+benchmark's cell ``gpac112x16g.kv.4chip`` (checked against its reference,
+with per-chip memory).
 
 Exits non-zero when JAX finds no TPU (no CPU fallback) or any check fails.
 The last line of standard output is the JSON result.
@@ -46,9 +46,7 @@ SEED = 0
 REF_FLEET = (2, 262_144)
 # (a'): every kernel on Pallas; bincount's O(ids x bins) form bounds the size
 PALLAS_FLEET = (4, 131_072)
-# four chips: the host-partitioned fleet (guests of GUEST_PAGES) and the
-# churn/migration fleet
-SHARDED_GUESTS = 16
+# four chips: the churn/migration fleet
 CHURN_FLEET = (8, 65_536)
 
 
@@ -231,37 +229,13 @@ def near_overcommit_free(cfg, state) -> bool:
     return perm and inverse and near <= cfg.n_near
 
 
-def four_chips(devs, clock: CompileClock, failures: list) -> None:
+def four_chips(failures: list) -> None:
     import jax
 
     from repro.core import engine, sharding
     from repro.launch import migration
 
     mesh = sharding.guest_mesh(4)
-    src = engine.SynthTrace(WINDOWS, ACCESSES)
-
-    spec, s0 = fleet(SHARDED_GUESTS, GUEST_PAGES)
-    (st, se), first_s, compile_s = timed(clock, lambda: engine.run_sharded(
-        spec, s0, src, mesh=mesh, host_sharded=True))
-    log(f"run_sharded(host_sharded=True) {SHARDED_GUESTS} guests x "
-        f"{GUEST_PAGES} pages on 4 chips: first call {first_s:.3f} s, of "
-        f"which compile {compile_s:.3f} s")
-    hit, near = tiering_outcome(se)
-    log(f"4-chip fleet: hit rate {hit:.6f}, near blocks held {near}")
-    check(near_overcommit_free(spec.cfg, st),
-          "block table is a permutation, no near overcommit", failures)
-    for d in devs:
-        log(f"memory {d.id}: peak_bytes_in_use {peak_bytes(d)}")
-    del st, se, s0
-
-    spec, s0 = fleet(N_GUESTS, GUEST_PAGES)
-    st4, se4 = engine.run_sharded(spec, s0, src, mesh=mesh, host_sharded=True)
-    one = jax.device_put(s0, devs[0])
-    st1, se1 = engine.run(spec, one, src)
-    check(same_bits(st4, st1) and same_bits(se4, se1),
-          f"{N_GUESTS} x {GUEST_PAGES} on 4 chips == on 1 device", failures)
-    del st4, se4, st1, se1, s0, one
-
     g, pages = CHURN_FLEET
     cspec, _ = fleet(g, pages)
     spare = g - 2  # vacant lane with guest 0's workload and CL
@@ -291,7 +265,7 @@ def main(argv=None) -> int:
     clock = CompileClock()
     failures: list[str] = []
     if args.chips == 4:
-        four_chips(devs[:4], clock, failures)
+        four_chips(failures)
     else:
         one_chip(devs[0], clock, failures)
     if failures:

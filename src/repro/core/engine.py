@@ -42,7 +42,8 @@ from repro.core import faults as faults_mod
 from repro.core import gpac, metrics, telemetry, tiering
 from repro.core import tiers as tiers_mod
 from repro.core.scopes import LAYERS, layer  # noqa: F401  (LAYERS re-exported)
-from repro.core.types import GpacConfig, TieredState, allocated_hp_mask, init_state
+from repro.core.types import (
+    GpacConfig, PartitionedState, TieredState, allocated_hp_mask, init_state)
 from repro.kernels import registry as kernels_registry
 
 
@@ -252,12 +253,19 @@ class EngineSpec:
 def build(
     guests: tuple[GuestSpec, ...] | list,
     host: HostSpec = HostSpec(),
-) -> tuple[EngineSpec, TieredState]:
+    *,
+    mesh=None,
+) -> tuple[EngineSpec, TieredState | PartitionedState]:
     """Build N (possibly ragged) guests over one shared host space.
 
     Returns the static :class:`EngineSpec` and the initial state: guest g's
     logical pages are identity-placed at the start of its own GPA segment
     (same layout the old ``make_multi_guest`` produced for symmetric guests).
+
+    With a ``mesh`` (:func:`repro.core.sharding.guest_mesh`) the state is
+    built in place in the host-partitioned layout that
+    :func:`run_sharded` keeps: each device builds only its own guests' part,
+    so a fleet larger than one device's memory never exists on one device.
     """
     guests = tuple(
         GuestSpec(n_logical=g) if isinstance(g, int) else g for g in guests
@@ -289,6 +297,11 @@ def build(
         dtype=host.dtype,
     )
     spec = EngineSpec(cfg, guests, logical_offsets, hp_offsets, tiers=tv)
+    if mesh is not None:
+        from repro.core import sharding
+
+        with jax.profiler.TraceAnnotation("tier.build"):
+            return spec, sharding.init_partitioned(spec, mesh)
     return spec, init_engine_state(spec)
 
 
@@ -985,7 +998,7 @@ HOST_SHARDED_COLLECTORS = ("hits", "near_blocks", "snapshot", "tco")
 
 def run_sharded(
     spec: EngineSpec,
-    state: TieredState,
+    state: TieredState | PartitionedState,
     source: TraceSource | np.ndarray | None = None,
     *,
     traces: np.ndarray | None = None,  # deprecated keyword (warns and wraps)
@@ -1001,7 +1014,7 @@ def run_sharded(
     collect: tuple[str, ...] = ("hits", "near_blocks"),
     kernel_backend: str | None = None,
     arbitration_stride: int | None = None,
-) -> tuple[TieredState, dict]:
+) -> tuple[TieredState | PartitionedState, dict]:
     """:func:`run`, device-sharded over the guest axis (DESIGN.md §9, §11).
 
     ``mesh`` is a 1-D ``"guest"`` mesh (:func:`repro.core.sharding.
@@ -1014,13 +1027,18 @@ def run_sharded(
     integer / bit-pattern collectives, and the host near-tier tick is
     deterministic either way.
 
-    ``host_sharded=True`` (the default) additionally partitions the host
-    state itself by contiguous block ranges (DESIGN.md §11): each device
-    carries only its own range of the block table, host telemetry and
-    payload, scores promotion/demotion locally, and one arbitration
-    exchange per window resolves cross-partition contention bit-for-bit
-    against the replicated tick -- per-device host-state bytes scale
-    ~1/n_devices (``sharding.host_state_bytes_sharded``). It requires a
+    ``host_sharded=True`` (the default) partitions the state itself
+    (DESIGN.md §11): each device holds only its own guests and its own
+    range of the block table, host telemetry and payload, scores
+    promotion/demotion locally, and one arbitration exchange per window
+    resolves cross-partition contention bit-for-bit against the replicated
+    tick -- per-device state bytes scale ~1/n_devices. Handed a
+    :class:`PartitionedState` (``build(..., mesh=mesh)``, or a previous
+    call's result) it returns one, and nothing replicated is ever built:
+    the path for fleets larger than one device. Handed a
+    :class:`TieredState` it lays it out partitioned once on entry
+    (``sharding.partition_state``) and gathers the result once on exit
+    (``sharding.gather_state``), for small fleets and tests. It requires a
     host-partitioned tick for ``policy`` (``tiering.sharded_ticks()``) and
     host-sharded collectors (:data:`HOST_SHARDED_COLLECTORS`);
     ``host_sharded=False`` keeps the replicated host state and supports any
@@ -1037,6 +1055,11 @@ def run_sharded(
     source = _coerce_source(source, traces)
     spec = _with_kernel_backend(spec, kernel_backend)
     spec = _with_arbitration_stride(spec, arbitration_stride)
+    partitioned = isinstance(state, PartitionedState)
+    if partitioned and (mesh is None or not host_sharded):
+        raise TypeError(
+            "a PartitionedState runs only host_sharded, on the mesh it was "
+            "laid out for: pass mesh=")
     if mesh is None:
         mesh = sharding.guest_mesh()
     if mesh is None:
@@ -1083,13 +1106,22 @@ def run_sharded(
                 raise ValueError(
                     f"host_sharded arbitration_stride={stride} must divide "
                     f"the run's n_windows={n_w}")
-            if int(np.asarray(state.epoch)) % stride:
+            epoch = int(np.asarray(state.epoch).reshape(-1)[0])
+            if epoch % stride:
                 raise ValueError(
                     f"host_sharded arbitration_stride={stride} needs the "
-                    f"state's epoch ({int(np.asarray(state.epoch))}) on an "
-                    f"arbitration boundary (epoch % stride == 0); pass "
-                    f"host_sharded=False to resume mid-stride")
-        _, tables = sharding.host_tables(spec, n_shards)
+                    f"state's epoch ({epoch}) on an arbitration boundary "
+                    f"(epoch % stride == 0); pass host_sharded=False to "
+                    f"resume mid-stride")
+        part, tables = sharding.host_tables(spec, n_shards)
+        if partitioned:
+            if sharding.n_shards_of(state) != n_shards or (
+                    state.gpt.shape[0] != n_shards * part.l_loc):
+                raise ValueError(
+                    "the PartitionedState was laid out for another spec or "
+                    "mesh")
+        else:
+            state = sharding.partition_state(spec, mesh, state)
 
         def chunk_fn(st, chunk):
             return sharding.run_chunk_host_sharded(
@@ -1116,7 +1148,10 @@ def run_sharded(
             f"host_sharded arbitration_stride={spec.arbitration_stride} "
             f"must divide the chunk size (windows_per_step resolved to "
             f"{wps}); pick a multiple of the stride")
-    return _drive_chunks(chunk_fn, state, by_window, wps, collect)
+    state, series = _drive_chunks(chunk_fn, state, by_window, wps, collect)
+    if host_sharded and not partitioned:
+        state = sharding.gather_state(spec, mesh, state)
+    return state, series
 
 
 def run_series(

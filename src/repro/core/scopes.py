@@ -27,3 +27,16 @@ def layer(name: str):
     if name not in LAYERS:
         raise ValueError(f"unknown engine layer {name!r} (have {LAYERS})")
     return jax.named_scope(name)
+
+
+# A scope inside ``tier.tick``, not a layer of its own: the host-partitioned
+# engine's arbitration exchange (the psum and the packing and unpacking of
+# its payload). Its ops keep ``tier.tick`` as their outermost layer; a trace
+# reader finds the exchange by this component of the op-name path.
+EXCHANGE = "tier.exchange"
+
+
+def exchange_scope():
+    """``jax.named_scope`` of :data:`EXCHANGE`; open it inside
+    ``layer("tier.tick")``."""
+    return jax.named_scope(EXCHANGE)

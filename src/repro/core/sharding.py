@@ -40,13 +40,14 @@ does: :func:`guest_mesh` returns ``None`` on a single-device host and
 ``engine.run_sharded`` falls back to ``engine.run``.
 
 The second half of this module is the **host-partitioned near tier**
-(DESIGN.md §11, ``engine.run_sharded(host_sharded=True)``): instead of
-replicating the host state, each device carries only its own contiguous
-block range (:class:`HostPartition`) with the payload stored per huge page,
-scores promotion/demotion locally, and one arbitration exchange per window
-(``repro.core.tiering``'s sharded ticks) resolves cross-partition
-contention bit-for-bit against the replicated tick -- per-device host-state
-bytes scale ~1/n_devices.
+(DESIGN.md §11, ``engine.run_sharded(host_sharded=True)``): nothing is
+replicated. The state stays partitioned between calls
+(:class:`repro.core.types.PartitionedState`): each device holds only its own
+guests and their contiguous block range (:class:`HostPartition`), with the
+payload stored per huge page, scores promotion/demotion locally, and one
+arbitration exchange per window (``repro.core.tiering``'s sharded ticks)
+resolves cross-partition contention bit-for-bit against the replicated
+tick -- per-device state bytes scale ~1/n_devices.
 """
 from __future__ import annotations
 
@@ -63,8 +64,8 @@ from repro.core import address_space as asp
 from repro.core import faults as faults_mod
 from repro.core import gpac, telemetry, tiering
 from repro.core import tiers as tiers_mod
-from repro.core.scopes import layer
-from repro.core.types import FREE, GpacConfig, TieredState
+from repro.core.scopes import exchange_scope, layer
+from repro.core.types import FREE, STATS, GpacConfig, PartitionedState, TieredState
 
 AXIS = "guest"
 
@@ -101,8 +102,8 @@ def collective_bytes() -> dict[str, int]:
     Sites: ``merge_window`` (replicated-host paths, one psum per window;
     the only collective the churn driver issues), ``host_exchange`` (the
     host-partitioned arbitration exchange, one psum per stride group) and
-    ``host_chunk_exit`` (host-partitioned chunk-boundary reconstruction,
-    one psum per chunk).
+    ``host_gather`` (:func:`gather_state`, the explicit gather of a
+    partitioned state into a replicated one).
     """
     return dict(_COLLECTIVE_BYTES)
 
@@ -709,49 +710,57 @@ def run_chunk_churn_sharded(
 # ==========================================================================
 # host-partitioned near tier (DESIGN.md §11)
 #
-# The replicated-host path above still gives every device the full host
-# state (block_table, slot pools, host telemetry), so per-device memory does
-# not scale with the mesh. The host-partitioned path carries the host state
-# **partitioned by contiguous block ranges**: device d owns exactly the huge
-# pages of its own guests' GPA segments (guest blocks are contiguous and
+# The replicated-host path above still gives every device the full state,
+# so per-device memory does not scale with the mesh. The host-partitioned
+# path keeps the whole state **partitioned** between calls
+# (:class:`repro.core.types.PartitionedState`): device d holds only its own
+# guests -- their logical and GPA segments, in the device's own coordinates,
+# and the huge pages of those segments (guest blocks are contiguous and
 # guests are dealt to devices in contiguous blocks, so guest ownership and
-# range ownership coincide), holding only
+# block-range ownership coincide):
 #
-#   * its local rows of block_table / host_counts / host_hist /
-#     last_touch_epoch / region_epoch, and
-#   * the **hp-owned payload** ``data[h - hp_lo]`` -- huge page h's bytes,
+#   * its guests' gpt / rmap / guest_counts / ipt_hist, as one small fleet
+#     of its own (``_local_cfg``: the same stage functions run on it);
+#   * its rows of block_table / host_counts / host_hist / last_touch_epoch /
+#     region_epoch, and
+#   * the **hp-owned payload** ``payload[h - hp_lo]`` -- huge page h's bytes,
 #     which equal the replicated ``pools[block_table[h]]`` row. Data follows
 #     the huge page, so an arbitrated promotion/demotion only relabels slots
 #     (block_table writes); no payload crosses devices, and ``slot_owner``
-#     (the label inverse) is reconstructed once per chunk.
+#     (the label inverse) is derived only where a replicated state is asked
+#     for.
 #
 # Per arbitration group (``EngineSpec.arbitration_stride`` windows; one
 # window by default) there is exactly ONE collective: per-partition tick
 # candidate sets (repro.core.tiering's sharded (prepare, apply) pairs), a
 # few scalar sums, and the stacked per-window collector rows share one
-# psum. The full TieredState
-# is materialized only at chunk boundaries (slice on entry, ownership-psum on
-# exit), so per-device host-state bytes scale ~1/n_shards for the whole scan.
+# psum. A replicated TieredState exists only where a caller asks for one
+# (:func:`partition_state` / :func:`gather_state`).
 # ==========================================================================
 # replicated host-state bytes per huge page: block_table + slot_owner +
 # host_counts + last_touch_epoch + region_epoch (int32) + host_hist (uint8)
 HOST_META_BYTES = 4 * 5 + 1
-# the partitioned carry drops slot_owner (reconstructed at chunk exit)
+# the partitioned carry drops slot_owner (derived from the block table)
 LOCAL_META_BYTES = 4 * 4 + 1
 
 
 @dataclasses.dataclass(frozen=True)
 class HostPartition:
-    """Contiguous per-device block ranges of the host near-tier state.
+    """Contiguous per-device ranges of the partitioned state.
 
-    Device ``d`` owns huge pages ``[hp_lo[d], hp_hi[d])`` -- its own guests'
-    GPA segments. Ranges tile ``[0, n_gpa_hp)``; devices holding only
-    padding guests own an empty range. ``h_loc`` is the widest range (every
-    device's local arrays are padded to it with -1 rows)."""
+    Device ``d`` owns huge pages ``[hp_lo[d], hp_hi[d])`` and logical pages
+    ``[l_lo[d], l_hi[d])`` -- its own guests' segments (``g_loc`` guest rows
+    per device). Ranges tile both spaces; devices holding only padding
+    guests own empty ranges. ``h_loc`` / ``l_loc`` are the widest ranges:
+    every device's arrays are padded to them."""
 
     hp_lo: tuple[int, ...]
     hp_hi: tuple[int, ...]
     h_loc: int
+    l_lo: tuple[int, ...]
+    l_hi: tuple[int, ...]
+    l_loc: int
+    g_loc: int
 
     @property
     def n_shards(self) -> int:
@@ -766,17 +775,22 @@ class HostPartition:
 
 
 def host_partition(spec, n_shards: int) -> HostPartition:
-    """Partition the host block space by the device-contiguous guest blocks
-    of ``engine.run_sharded``'s guest dealing."""
+    """Partition the host block space and the logical space by the
+    device-contiguous guest blocks of ``engine.run_sharded``'s guest
+    dealing."""
     g_loc = padded_guest_count(spec.n_guests, n_shards) // n_shards
-    lo, hi = [], []
+    hp_lo, hp_hi, l_lo, l_hi = [], [], [], []
     for d in range(n_shards):
         a = min(d * g_loc, spec.n_guests)
         z = min((d + 1) * g_loc, spec.n_guests)
-        lo.append(spec.hp_offsets[a])
-        hi.append(spec.hp_offsets[z])
-    h_loc = max(1, max(h - l for l, h in zip(lo, hi)))
-    return HostPartition(tuple(lo), tuple(hi), h_loc)
+        hp_lo.append(spec.hp_offsets[a])
+        hp_hi.append(spec.hp_offsets[z])
+        l_lo.append(spec.logical_offsets[a])
+        l_hi.append(spec.logical_offsets[z])
+    h_loc = max(1, max(h - l for l, h in zip(hp_lo, hp_hi)))
+    l_loc = max(1, max(h - l for l, h in zip(l_lo, l_hi)))
+    return HostPartition(tuple(hp_lo), tuple(hp_hi), h_loc, tuple(l_lo),
+                         tuple(l_hi), l_loc, g_loc)
 
 
 def host_state_bytes(cfg: GpacConfig) -> int:
@@ -794,16 +808,69 @@ def host_state_bytes_sharded(cfg: GpacConfig, part: HostPartition) -> int:
 
 
 def host_tables(spec, n_shards: int) -> tuple[HostPartition, dict]:
-    """Guest segment tables plus the host-partition tables the partitioned
-    chunk driver shards over the mesh."""
+    """The partition and the tables the partitioned drivers shard over the
+    mesh: per guest row (``[G_pad]``) its logical and huge-page segment in
+    its device's coordinates (start, size) and its Consolidation Limit; per
+    device (``[n_shards]``) its global ranges. Padding rows start at their
+    device's end with size 0."""
     part = host_partition(spec, n_shards)
-    tables = guest_tables(spec, n_shards)
-    tables.update(
-        hp_ids=part.hp_ids(),
-        hp_lo=np.asarray(part.hp_lo, np.int32),
-        hp_hi=np.asarray(part.hp_hi, np.int32),
+    g_pad = part.g_loc * n_shards
+    dev = np.arange(g_pad) // part.g_loc
+    real = np.arange(g_pad) < spec.n_guests
+    rows = np.minimum(np.arange(g_pad), spec.n_guests - 1)
+    lo_l = np.asarray(spec.logical_offsets, np.int64)
+    lo_h = np.asarray(spec.hp_offsets, np.int64)
+    l_lo, l_hi = np.asarray(part.l_lo), np.asarray(part.l_hi)
+    hp_lo, hp_hi = np.asarray(part.hp_lo), np.asarray(part.hp_hi)
+
+    def seg(lo, dev_lo, dev_hi):
+        start = np.where(real, lo[rows] - dev_lo[dev], dev_hi[dev] - dev_lo[dev])
+        size = np.where(real, lo[rows + 1] - lo[rows], 0)
+        return start.astype(np.int32), size.astype(np.int32)
+
+    l_start, l_size = seg(lo_l, l_lo, l_hi)
+    h_start, h_size = seg(lo_h, hp_lo, hp_hi)
+    cls = np.asarray([spec.guest_cl(g) for g in range(spec.n_guests)], np.int32)
+    tables = dict(
+        l_start=l_start, l_size=l_size, h_start=h_start, h_size=h_size,
+        cl=np.where(real, cls[rows], 1).astype(np.int32),
+        hp_lo=hp_lo.astype(np.int32), hp_hi=hp_hi.astype(np.int32),
+        l_lo=l_lo.astype(np.int32), l_hi=l_hi.astype(np.int32),
     )
     return part, tables
+
+
+def _mesh_tables(spec, mesh) -> dict:
+    """:func:`host_tables` for ``mesh``, as arrays."""
+    _, tables = host_tables(spec, mesh_size(mesh))
+    return {k: jnp.asarray(v) for k, v in tables.items()}
+
+
+def _local_cfg(cfg: GpacConfig, part: HostPartition) -> GpacConfig:
+    """One device's guest-major geometry: its guests as a fleet of their
+    own. Slots stay the host's, so every slot test uses ``cfg``; the local
+    ``n_near`` is a placeholder no stage reads."""
+    return dataclasses.replace(
+        cfg, n_logical=part.l_loc, n_gpa_hp=part.h_loc, n_near=1)
+
+
+def _pad_rows(start: jax.Array, size: jax.Array, width: int) -> jax.Array:
+    """int32[rows, width]: row g = ``start[g] + col`` where ``col <
+    size[g]``, -1 past it (a device's segment-table rows, made in place)."""
+    col = jnp.arange(width, dtype=jnp.int32)[None, :]
+    return jnp.where(col < size[:, None], start[:, None] + col, -1)
+
+
+def _device_tables(part: HostPartition, tab: dict) -> dict:
+    """This device's blocks of :func:`host_tables` as the window bodies use
+    them: per-device scalars, and the global id of each block row."""
+    out = dict(tab)
+    for k in ("hp_lo", "hp_hi", "l_lo", "l_hi"):
+        out[k] = tab[k][0]
+    col = jnp.arange(part.h_loc, dtype=jnp.int32)
+    out["hp_ids"] = jnp.where(
+        col < out["hp_hi"] - out["hp_lo"], out["hp_lo"] + col, -1)
+    return out
 
 
 def _slice_host_local(cfg: GpacConfig, state: TieredState, hp_ids: jax.Array) -> dict:
@@ -836,18 +903,13 @@ def _slice_host_local(cfg: GpacConfig, state: TieredState, hp_ids: jax.Array) ->
     )
 
 
-def _spread_hp(x_loc: jax.Array, hp_ids: jax.Array, n: int, fill) -> jax.Array:
-    """Scatter local block rows into a full-shape view filled with ``fill``
-    elsewhere (only ever *read* at this device's own blocks)."""
-    safe = jnp.where(hp_ids >= 0, hp_ids, n)
-    return jnp.full((n + 1,), fill, x_loc.dtype).at[safe].set(x_loc)[:n]
-
-
-def _scatter_zero(x_loc: jax.Array, hp_ids: jax.Array, n: int) -> jax.Array:
-    """Local rows placed at their global positions in zeros: summed across
-    devices (ranges tile the space) this reconstructs the full array."""
-    safe = jnp.where(hp_ids >= 0, hp_ids, n)
-    return jnp.zeros((n + 1,), x_loc.dtype).at[safe].set(x_loc)[:n]
+def _scatter_zero(x_loc: jax.Array, ids: jax.Array, n: int) -> jax.Array:
+    """Local rows placed at their global positions ``ids`` (-1 = none) in
+    zeros: summed across devices (ranges tile the space) this reconstructs
+    the full array."""
+    safe = jnp.where(ids >= 0, ids, n)
+    return jnp.zeros((n + 1,) + x_loc.shape[1:], x_loc.dtype).at[safe].set(
+        x_loc)[:n]
 
 
 def _bits(x: jax.Array) -> jax.Array:
@@ -885,9 +947,9 @@ def _place_block(x: jax.Array, n_shards: int) -> jax.Array:
     ].set(x)
 
 
-def _view_state(cfg, gpt, rmap, gc, ih, re_view, epoch, stats) -> TieredState:
-    """A TieredState view for the guest-side GPAC classifiers: real guest
-    arrays + the local region_epoch spread, placeholder host arrays (the
+def _view_state(cfg, gpt, rmap, gc, ih, re_loc, epoch, stats) -> TieredState:
+    """A TieredState view for the guest-side GPAC classifiers: the device's
+    guest arrays and block-range region_epoch, placeholder host arrays (the
     telemetry/filter path never reads block tables or pools)."""
     z = jnp.zeros((1,), jnp.int32)
     zp = jnp.zeros((1, 1, 1), cfg.dtype)
@@ -895,19 +957,46 @@ def _view_state(cfg, gpt, rmap, gc, ih, re_view, epoch, stats) -> TieredState:
         gpt=gpt, rmap=rmap, block_table=z, slot_owner=z, near_pool=zp,
         far_pool=zp, guest_counts=gc, ipt_hist=ih, host_counts=z,
         host_hist=jnp.zeros((1,), jnp.uint8), last_touch_epoch=z,
-        region_epoch=re_view, epoch=epoch, stats=stats,
+        region_epoch=re_loc, epoch=epoch, stats=stats,
     )
 
 
+def _hot_mask(cfg: GpacConfig, lcfg: GpacConfig, view: TieredState,
+              backend: str, l_lo: jax.Array) -> jax.Array:
+    """The classifier on the device's own pages. A page-local classifier
+    (``ipt``) is exact on the local view. The others (the sampled and
+    region ones) draw and group by global page position: they classify a
+    global-shaped view with this device's counts in place (zeros elsewhere)
+    and read back this device's rows."""
+    if telemetry.is_page_local(backend):
+        return telemetry.hot_mask(lcfg, view, backend)
+    n, w = cfg.n_logical, lcfg.n_logical
+
+    def spread(x):
+        buf = jnp.zeros((n + w,), x.dtype)
+        return jax.lax.dynamic_update_slice(buf, x, (l_lo,))[:n]
+
+    glob = dataclasses.replace(
+        view, guest_counts=spread(view.guest_counts),
+        ipt_hist=spread(view.ipt_hist))
+    hot = telemetry.hot_mask(cfg, glob, backend)
+    return jax.lax.dynamic_slice(
+        jnp.concatenate([hot, jnp.zeros((w,), hot.dtype)]), (l_lo,), (w,))
+
+
+def _cl_rows(lcfg: GpacConfig, T: dict) -> jax.Array:
+    """int32[l_loc]: the CL of the local guest owning each local page."""
+    ids = jnp.arange(lcfg.n_logical, dtype=jnp.int32)
+    return T["cl"][jnp.searchsorted(T["l_start"][1:], ids, side="right")]
+
+
 def _near_blocks_local(cfg: GpacConfig, alloc: jax.Array, bt: jax.Array,
-                       hp_lo: jax.Array, hp_pad: jax.Array) -> jax.Array:
+                       hp_pad: jax.Array) -> jax.Array:
     """Per own guest: allocated blocks currently in the near tier, counted
-    over this device's local block rows (pre-tick; the arbitrated swap
-    deltas correct it to post-tick replicatedly)."""
-    h_loc = bt.shape[0]
-    row = jnp.clip(jnp.where(hp_pad >= 0, hp_pad - hp_lo, 0), 0, h_loc - 1)
-    good = alloc & (bt < cfg.n_near)
-    seg = (hp_pad >= 0) & good[row]
+    over this device's block rows (pre-tick; the arbitrated swap deltas
+    correct it to post-tick replicatedly)."""
+    row = jnp.maximum(hp_pad, 0)
+    seg = (hp_pad >= 0) & (alloc & (bt < cfg.n_near))[row]
     return seg.sum(axis=1).astype(jnp.int32)
 
 
@@ -954,15 +1043,11 @@ def _near_scalar_delta(cfg: GpacConfig, swaps) -> jax.Array:
 
 def _host_sharded_group(
     spec,
+    lcfg: GpacConfig,  # this device's guest-major geometry (_local_cfg)
     n_shards: int,
     carry: dict,
     accs: jax.Array,  # int32[stride, G_loc, k]
-    logical_lo: jax.Array,
-    logical_pad: jax.Array,
-    hp_pad: jax.Array,
-    hp_ids: jax.Array,
-    hp_lo: jax.Array,
-    hp_hi: jax.Array,
+    T: dict,  # this device's tables (_device_tables)
     policy: str,
     backend: str,
     use_gpac: bool,
@@ -973,19 +1058,19 @@ def _host_sharded_group(
     w_next: jax.Array | None = None,
 ) -> tuple[dict, dict]:
     """One arbitration *group* -- ``spec.arbitration_stride`` engine windows
-    -- on the partitioned host state, with exactly ONE collective for the
-    whole group. Bit-for-bit equal to ``engine._window`` at the same stride
-    on the unpadded guests (stride 1 is the classic one-window body).
+    -- on the partitioned state, with exactly ONE collective for the whole
+    group. Bit-for-bit equal to ``engine._window`` at the same stride on the
+    unpadded guests (stride 1 is the classic one-window body).
 
     Every window runs its access + GPAC phases and its telemetry roll
-    locally; only the group's last window nominates tick candidates
-    (arbitrating on the stride's accumulated telemetry). The per-window
-    collector rows -- hits, pre-tick near-block counts, tier vectors,
-    snapshot deltas -- are stacked and ride the last window's candidate
-    exchange, so ``stride`` windows cost one psum instead of ``stride``.
-    The arbitrated swap deltas correct only the last window's emissions:
-    the earlier windows ran no tick, so their pre-tick counts *are* their
-    post-window placement.
+    locally, in the device's own coordinates; only the group's last window
+    nominates tick candidates (arbitrating on the stride's accumulated
+    telemetry). The per-window collector rows -- hits, pre-tick near-block
+    counts, tier vectors, snapshot deltas -- are stacked and ride the last
+    window's candidate exchange, so ``stride`` windows cost one psum
+    instead of ``stride``. The arbitrated swap deltas correct only the last
+    window's emissions: the earlier windows ran no tick, so their pre-tick
+    counts *are* their post-window placement.
 
     ``prefetch`` overlaps the collective with trace synthesis (DESIGN.md
     §17): issued right after the psum, the next group's accesses
@@ -1009,6 +1094,8 @@ def _host_sharded_group(
     epoch_in = epoch
     kb = spec.kernel_backend
     tv = spec.tier_vector if "tco" in collect else None
+    hp_ids = T["hp_ids"]
+    hp_pad = _pad_rows(T["h_start"], T["h_size"], spec.max_hp)
     prepare, apply = tiering.sharded_tick_fns(policy)
     if spec.tiers is not None:
         prepare = partial(prepare, tiers=spec.tiers)
@@ -1021,25 +1108,21 @@ def _host_sharded_group(
         accesses = accs[j]
         # ---- 1. access phase (local: own guests touch own blocks) -------
         with layer("tier.translate"):
-            ids = jnp.where(accesses >= 0, accesses + logical_lo[:, None], -1)
-            valid = (ids >= 0) & (ids < cfg.n_logical)
+            ids = jnp.where(accesses >= 0, accesses + T["l_start"][:, None], -1)
+            valid = (ids >= 0) & (ids < lcfg.n_logical)
             hp = gpt[jnp.where(valid, ids, 0)] // cfg.hp_ratio
-            bt_view = _spread_hp(
-                loc["bt"], hp_ids, cfg.n_gpa_hp, jnp.int32(cfg.n_gpa_hp))
-            slot = bt_view[hp]
+            slot = loc["bt"][hp]
             near_loc = (
                 (valid & (slot < cfg.n_near)).sum(axis=1).astype(jnp.int32))
             far_loc = (
                 (valid & (slot >= cfg.n_near)).sum(axis=1).astype(jnp.int32))
         with layer("tier.record"):
-            h = asp.access_histogram(cfg, ids, valid, kb)
+            h = asp.access_histogram(lcfg, ids, valid, kb)
             gc = gc + h
-            inc_full = asp.host_histogram(cfg, gpt, h, kb)
-            inc_loc = jnp.where(
-                hp_ids >= 0, inc_full[jnp.maximum(hp_ids, 0)], 0)
-            loc["hc"] = loc["hc"] + inc_loc
+            inc = asp.host_histogram(lcfg, gpt, h, kb)
+            loc["hc"] = loc["hc"] + inc
             loc["lt"] = jnp.where(
-                inc_loc > 0, jnp.maximum(loc["lt"], epoch), loc["lt"])
+                inc > 0, jnp.maximum(loc["lt"], epoch), loc["lt"])
         with layer("tier.translate"):
             stats["near_hits"] = stats["near_hits"] + near_loc.sum()
             stats["far_hits"] = stats["far_hits"] + far_loc.sum()
@@ -1047,24 +1130,23 @@ def _host_sharded_group(
         # ---- 2. GPAC phase (own segment rows, hp-owned payload) ---------
         if use_gpac:
             with layer("tier.classify"):
-                re_view = _spread_hp(
-                    loc["re"], hp_ids, cfg.n_gpa_hp, jnp.int32(-1))
                 view = _view_state(
-                    cfg, gpt, rmap, gc, ih, re_view, epoch, stats)
-                hot = telemetry.hot_mask(cfg, view, backend)
+                    lcfg, gpt, rmap, gc, ih, loc["re"], epoch, stats)
+                hot = _hot_mask(cfg, lcfg, view, backend, T["l_lo"])
             with layer("tier.score"):
                 score = pfilter.candidate_score(
-                    cfg, view, hot, jnp.asarray(spec.cl_per_logical()), kb
-                )
+                    lcfg, view, hot, _cl_rows(lcfg, T), kb)
             with layer("tier.select"):
                 batches = pfilter.select_batches_from_rows(
-                    cfg, score, logical_pad, max_batches, kb
+                    lcfg, score,
+                    _pad_rows(T["l_start"], T["l_size"], spec.max_logical),
+                    max_batches, kb,
                 )
             with layer("tier.copy"):
                 gpt, rmap, loc["data"], loc["re"], stats = (
                     consolidator.consolidate_rounds_local(
-                        cfg, gpt, rmap, loc["data"], loc["re"], epoch, stats,
-                        batches, hp_pad, hp_lo, kb,
+                        lcfg, gpt, rmap, loc["data"], loc["re"], epoch, stats,
+                        batches, hp_pad, jnp.int32(0), kb,
                     )
                 )
 
@@ -1077,18 +1159,15 @@ def _host_sharded_group(
         # the tick's are replicated and added after arbitration) and its
         # local allocated / allocated-near block counts.
         with layer("tier.roll"):
-            alloc_full = (
-                rmap.reshape(cfg.n_gpa_hp, cfg.hp_ratio) != FREE).any(axis=1)
-            alloc_loc = jnp.where(
-                hp_ids >= 0, alloc_full[jnp.maximum(hp_ids, 0)], False)
+            alloc_loc = (
+                rmap.reshape(lcfg.n_gpa_hp, cfg.hp_ratio) != FREE).any(axis=1)
             contrib = dict(
                 near=_spread_rows(near_loc, n_shards),
                 far=_spread_rows(far_loc, n_shards),
             )
             if "near_blocks" in collect:
                 contrib["near_blocks"] = _spread_rows(
-                    _near_blocks_local(
-                        cfg, alloc_loc, loc["bt"], hp_lo, hp_pad),
+                    _near_blocks_local(cfg, alloc_loc, loc["bt"], hp_pad),
                     n_shards,
                 )
             if "tco" in collect:
@@ -1118,32 +1197,32 @@ def _host_sharded_group(
         else:
             # ---- 3b. nominate on the stride's accumulated telemetry -----
             L = dict(
-                hp_ids=hp_ids, hp_lo=hp_lo, hp_hi=hp_hi, bt=loc["bt"],
-                hc=loc["hc"], hh=loc["hh"], lt=loc["lt"], alloc=alloc_loc,
+                hp_ids=hp_ids, hp_lo=T["hp_lo"], hp_hi=T["hp_hi"],
+                bt=loc["bt"], hc=loc["hc"], hh=loc["hh"], lt=loc["lt"],
+                alloc=alloc_loc,
             )
             with layer("tier.tick"):
                 payload = prepare(cfg, L, budget)
 
     # ---- 3c. the group's single collective ------------------------------
-    exchange = dict(
-        cands=jax.tree_util.tree_map(
-            lambda x: _place_block(x, n_shards), payload["cands"]
-        ),
-        sums=payload["sums"],
-        win=jax.tree_util.tree_map(lambda *x: jnp.stack(x), *per_win),
-    )
-    merged = _psum_counted("host_exchange", exchange)
+    with layer("tier.tick"), exchange_scope():
+        merged = _psum_counted("host_exchange", dict(
+            cands=jax.tree_util.tree_map(
+                lambda x: _place_block(x, n_shards), payload["cands"]
+            ),
+            sums=payload["sums"],
+            win=jax.tree_util.tree_map(lambda *x: jnp.stack(x), *per_win),
+        ))
+        mwin = merged["win"]
+        nominated = dict(cands=merged["cands"], sums=merged["sums"])
     if prefetch is not None:
         # next group's accesses: no data dependency on ``merged``, so the
         # synthesis can run while the exchange is in flight
         acc_next = prefetch(w_next)
-    mwin = merged["win"]
 
     # ---- 4. arbitration: replicated decisions, local block-table writes -
     with layer("tier.tick"):
-        loc["bt"], tick_stats, swaps = apply(
-            cfg, L, dict(cands=merged["cands"], sums=merged["sums"]), budget
-        )
+        loc["bt"], tick_stats, swaps = apply(cfg, L, nominated, budget)
         on_d0 = jax.lax.axis_index(AXIS) == 0
         for s in tick_stats:  # replicated deltas: count them on one device
             stats[s] = stats[s] + jnp.where(on_d0, tick_stats[s], 0)
@@ -1226,59 +1305,35 @@ def _host_sharded_group(
     return new_carry, out
 
 
-def _merge_host_final(
-    cfg: GpacConfig,
-    base: TieredState,
-    carry: dict,
-    logical_pad: jax.Array,
-    hp_pad: jax.Array,
-    hp_ids: jax.Array,
-) -> TieredState:
-    """Chunk-exit reconstruction of the replicated TieredState: one psum of
-    ownership-placed contributions (segment rows for guest arrays, block
-    ranges for host arrays, bit patterns for the pools), then ``slot_owner``
-    recomputed as the merged block table's inverse -- exactly the inverse
-    :func:`tiering.swap_blocks` maintains."""
-    loc = carry["loc"]
-    own_logical = _own_mask(logical_pad, cfg.n_logical)
-    own_gpa = jnp.repeat(_own_mask(hp_pad, cfg.n_gpa_hp), cfg.hp_ratio)
-    d0 = (jax.lax.axis_index(AXIS) == 0).astype(jnp.int32)
-    contrib = dict(
-        gpt=_owned_bits(carry["gpt"], own_logical),
-        rmap=_owned_bits(carry["rmap"], own_gpa),
-        guest_counts=_owned_bits(carry["guest_counts"], own_logical),
-        ipt_hist=_owned_bits(carry["ipt_hist"], own_logical),
-        bt=_scatter_zero(loc["bt"], hp_ids, cfg.n_gpa_hp),
-        hc=_scatter_zero(loc["hc"], hp_ids, cfg.n_gpa_hp),
-        hh=_scatter_zero(loc["hh"], hp_ids, cfg.n_gpa_hp),
-        lt=_scatter_zero(loc["lt"], hp_ids, cfg.n_gpa_hp),
-        re=_scatter_zero(loc["re"], hp_ids, cfg.n_gpa_hp),
-        near=_pool_contrib(cfg, loc, hp_ids, near=True),
-        far=_pool_contrib(cfg, loc, hp_ids, near=False),
-        stats={k: carry["stats"][k] - base.stats[k] for k in base.stats},
-        epoch=(carry["epoch"] - base.epoch) * d0,
+def _carry_in(ps: PartitionedState) -> dict:
+    """A device's block of the partitioned state as the group body's carry."""
+    return dict(
+        gpt=ps.gpt, rmap=ps.rmap, guest_counts=ps.guest_counts,
+        ipt_hist=ps.ipt_hist, epoch=ps.epoch[0],
+        stats={k: v[0] for k, v in ps.stats.items()},
+        loc=dict(bt=ps.block_table, hc=ps.host_counts, hh=ps.host_hist,
+                 lt=ps.last_touch_epoch, re=ps.region_epoch, data=ps.payload),
     )
-    m = _psum_counted("host_chunk_exit", contrib)
-    slot_owner = jnp.zeros((cfg.n_slots,), jnp.int32).at[m["bt"]].set(
-        jnp.arange(cfg.n_gpa_hp, dtype=jnp.int32)
+
+
+def _carry_out(c: dict) -> PartitionedState:
+    loc = c["loc"]
+    return PartitionedState(
+        gpt=c["gpt"], rmap=c["rmap"], guest_counts=c["guest_counts"],
+        ipt_hist=c["ipt_hist"], block_table=loc["bt"], host_counts=loc["hc"],
+        host_hist=loc["hh"], last_touch_epoch=loc["lt"],
+        region_epoch=loc["re"], payload=loc["data"], epoch=c["epoch"][None],
+        stats={k: v[None] for k, v in c["stats"].items()},
     )
-    return dataclasses.replace(
-        base,
-        gpt=m["gpt"],
-        rmap=m["rmap"],
-        guest_counts=m["guest_counts"],
-        ipt_hist=m["ipt_hist"],
-        block_table=m["bt"],
-        slot_owner=slot_owner,
-        host_counts=m["hc"],
-        host_hist=m["hh"],
-        last_touch_epoch=m["lt"],
-        region_epoch=m["re"],
-        near_pool=_from_bits(m["near"], base.near_pool),
-        far_pool=_from_bits(m["far"], base.far_pool),
-        stats={k: base.stats[k] + m["stats"][k] for k in base.stats},
-        epoch=base.epoch + m["epoch"],
-    )
+
+
+def _partitioned_fn(mesh, body, in_specs, out_specs):
+    return jax.jit(shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        # psum results are replicated but the replication check cannot
+        # always infer it; correctness is pinned by the equivalence tests
+        check_vma=False,
+    ))
 
 
 @lru_cache(maxsize=64)
@@ -1293,65 +1348,50 @@ def _host_chunk_fn(
     collect: tuple[str, ...],
     plan=None,  # repro.data.traces.SynthPlan for on-device synthesis
 ):
-    """Compiled host-partitioned chunk driver: slice the replicated state
-    into per-device ranges, scan the *arbitration groups* (``spec.
-    arbitration_stride`` windows each; stride 1 = one window per group) on
-    the partitioned carry, merge back once at the chunk boundary. With a
-    ``plan``, each device synthesizes its local guests' accesses inside the
-    group (same gid-folded key discipline as :func:`_chunk_fn`) -- one
-    group *ahead*, so the synthesis of the next group's accesses overlaps
-    the in-flight candidate exchange (DESIGN.md §17)."""
+    """Compiled host-partitioned chunk driver: scan the *arbitration
+    groups* (``spec.arbitration_stride`` windows each; stride 1 = one window
+    per group) on the partitioned state, which comes in and goes out
+    partitioned. With a ``plan``, each device synthesizes its local guests'
+    accesses inside the group (same gid-folded key discipline as
+    :func:`_chunk_fn`) -- one group *ahead*, so the synthesis of the next
+    group's accesses overlaps the in-flight candidate exchange (DESIGN.md
+    §17)."""
     n_shards = mesh_size(mesh)
-    cfg = spec.cfg
+    part = host_partition(spec, n_shards)
+    lcfg = _local_cfg(spec.cfg, part)
     stride = spec.arbitration_stride
 
-    def scan_chunk(state, xs, window, hp_ids, acc0=None):
-        carry = dict(
-            gpt=state.gpt, rmap=state.rmap, guest_counts=state.guest_counts,
-            ipt_hist=state.ipt_hist, epoch=state.epoch, stats=state.stats,
-            loc=_slice_host_local(cfg, state, hp_ids),
-        )
+    def group(c, accs, T, **kw):
+        return _host_sharded_group(
+            spec, lcfg, n_shards, c, accs, T, policy, backend, use_gpac,
+            max_batches, budget, collect, **kw)
+
+    def scan_chunk(ps, xs, window, acc0=None):
+        carry = _carry_in(ps)
         if "snapshot" in collect:
-            carry["gstats"] = dict(state.stats)
+            carry["gstats"] = jax.lax.psum(carry["stats"], AXIS)
         if acc0 is not None:
             carry["acc"] = acc0
         carry, ys = jax.lax.scan(window, carry, xs)
         # [n_groups, stride, ...] -> [n_windows, ...]
         ys = jax.tree_util.tree_map(
             lambda y: y.reshape((y.shape[0] * y.shape[1],) + y.shape[2:]), ys)
-        return carry, ys
+        return _carry_out(carry), ys
 
     if plan is None:
 
-        def body(state, chunk, logical_lo, logical_pad, hp_pad,
-                 hp_ids, hp_lo, hp_hi):
-            hp_ids, hp_lo, hp_hi = hp_ids[0], hp_lo[0], hp_hi[0]
+        def body(ps, chunk, tab):
+            T = _device_tables(part, tab)
             groups = chunk.reshape(
                 (chunk.shape[0] // stride, stride) + chunk.shape[1:])
+            return scan_chunk(ps, groups, lambda c, accs: group(c, accs, T))
 
-            def window(c, accs):
-                return _host_sharded_group(
-                    spec, n_shards, c, accs, logical_lo, logical_pad, hp_pad,
-                    hp_ids, hp_lo, hp_hi, policy, backend, use_gpac,
-                    max_batches, budget, collect,
-                )
-
-            carry, ys = scan_chunk(state, groups, window, hp_ids)
-            return (
-                _merge_host_final(cfg, state, carry, logical_pad, hp_pad, hp_ids),
-                ys,
-            )
-
-        in_specs = (
-            P(), P(None, AXIS, None), P(AXIS), P(AXIS, None), P(AXIS, None),
-            P(AXIS, None), P(AXIS), P(AXIS),
-        )
+        in_specs = (P(AXIS), P(None, AXIS, None), P(AXIS))
     else:
         from repro.data import traces as tr
 
-        def body(state, widx, logical_lo, logical_pad, hp_pad,
-                 hp_ids, hp_lo, hp_hi, seeds, gids, wid, n_logical):
-            hp_ids, hp_lo, hp_hi = hp_ids[0], hp_lo[0], hp_hi[0]
+        def body(ps, widx, tab, seeds, gids, wid, n_logical):
+            T = _device_tables(part, tab)
             setup = tr.synth_setup(plan, dict(
                 seeds=seeds, gids=gids, wid=wid, n_logical=n_logical))
             wg = widx.reshape(widx.shape[0] // stride, stride)
@@ -1363,46 +1403,27 @@ def _host_chunk_fn(
                 ])
 
             def window(c, ws_next):
-                return _host_sharded_group(
-                    spec, n_shards, c, c["acc"], logical_lo, logical_pad,
-                    hp_pad, hp_ids, hp_lo, hp_hi, policy, backend, use_gpac,
-                    max_batches, budget, collect,
-                    prefetch=synth_group, w_next=ws_next,
-                )
+                return group(c, c["acc"], T, prefetch=synth_group,
+                             w_next=ws_next)
 
             # the scan consumes the carry's pre-synthesized group and
             # prefetches the *next* one behind the psum; the trailing dummy
             # indices (last group + stride) synthesize one discarded group
             w_next = jnp.concatenate([wg[1:], wg[-1:] + stride], axis=0)
-            carry, ys = scan_chunk(
-                state, w_next, window, hp_ids, acc0=synth_group(wg[0]))
-            return (
-                _merge_host_final(cfg, state, carry, logical_pad, hp_pad, hp_ids),
-                ys,
-            )
+            return scan_chunk(ps, w_next, window, acc0=synth_group(wg[0]))
 
-        in_specs = (
-            P(), P(None), P(AXIS), P(AXIS, None), P(AXIS, None),
-            P(AXIS, None), P(AXIS), P(AXIS),
-            P(AXIS), P(AXIS), P(AXIS), P(AXIS),
-        )
+        in_specs = (P(AXIS), P(None), P(AXIS), P(AXIS), P(AXIS), P(AXIS),
+                    P(AXIS))
 
-    sharded = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=P(),
-        check_vma=False,
-    )
-    return jax.jit(sharded)
+    return _partitioned_fn(mesh, body, in_specs, (P(AXIS), P()))
 
 
 def run_chunk_host_sharded(
     spec,
     mesh,
-    state: TieredState,
+    state: PartitionedState,
     chunk: jax.Array,  # int32[n_windows, G_pad, k], or int32[n_windows]
-    tables: dict,      # window indices when plan is given
+    tables: dict,      # host_tables(spec, n_shards)[1]
     *,
     policy: str,
     backend: str,
@@ -1412,23 +1433,210 @@ def run_chunk_host_sharded(
     collect: tuple[str, ...],
     plan=None,
     synth_tables: dict | None = None,
-) -> tuple[TieredState, dict]:
+) -> tuple[PartitionedState, dict]:
     """One scan-fused chunk of the host-partitioned engine
-    (``engine.run_sharded(host_sharded=True)``'s inner loop)."""
+    (``engine.run_sharded(host_sharded=True)``'s inner loop): the state
+    comes in and goes out partitioned."""
     fn = _host_chunk_fn(
         spec, mesh, policy, backend, use_gpac, max_batches, budget, collect,
         plan,
     )
-    args = (
-        state,
-        chunk,
-        jnp.asarray(tables["logical_lo"]),
-        jnp.asarray(tables["logical_pad"]),
-        jnp.asarray(tables["hp_pad"]),
-        jnp.asarray(tables["hp_ids"]),
-        jnp.asarray(tables["hp_lo"]),
-        jnp.asarray(tables["hp_hi"]),
-    )
+    args = (state, chunk, {k: jnp.asarray(v) for k, v in tables.items()})
     if plan is not None:
         args += _synth_args(synth_tables)
     return fn(*args)
+
+
+# --------------------------------------------------------------------------
+# the partitioned state: built in place, or converted on request
+# --------------------------------------------------------------------------
+def n_shards_of(state: PartitionedState) -> int:
+    """The mesh size a partitioned state was laid out for."""
+    return state.epoch.shape[0]
+
+
+@lru_cache(maxsize=16)
+def _init_fn(spec, mesh):
+    cfg = spec.cfg
+    R = cfg.hp_ratio
+    part = host_partition(spec, mesh_size(mesh))
+
+    def body(tab):
+        T = _device_tables(part, tab)
+        i = jnp.arange(part.l_loc, dtype=jnp.int32)
+        g = jnp.searchsorted(T["l_start"][1:], i, side="right")
+        gpt = jnp.where(i < T["l_hi"] - T["l_lo"],
+                        T["h_start"][g] * R + i - T["l_start"][g], -1)
+        j = jnp.arange(part.h_loc * R, dtype=jnp.int32)
+        gh = jnp.searchsorted(T["h_start"][1:], j // R, side="right")
+        off = j - T["h_start"][gh] * R
+        rmap = jnp.where((j // R < T["hp_hi"] - T["hp_lo"])
+                         & (off < T["l_size"][gh]),
+                         T["l_start"][gh] + off, FREE)
+        zl = jnp.zeros((part.l_loc,), jnp.int32)
+        zh = jnp.zeros((part.h_loc,), jnp.int32)
+        one = jnp.zeros((1,), jnp.int32)
+        return PartitionedState(
+            gpt=gpt, rmap=rmap, guest_counts=zl,
+            ipt_hist=zl.astype(jnp.uint8),
+            block_table=jnp.where(T["hp_ids"] >= 0, T["hp_ids"], cfg.n_gpa_hp),
+            host_counts=zh, host_hist=zh.astype(jnp.uint8),
+            last_touch_epoch=zh, region_epoch=zh - 1,
+            payload=jnp.zeros((part.h_loc, R, cfg.base_elems), cfg.dtype),
+            epoch=one,
+            stats={k: one for k in STATS},
+        )
+
+    return _partitioned_fn(mesh, body, (P(AXIS),), P(AXIS))
+
+
+def init_partitioned(spec, mesh) -> PartitionedState:
+    """The fresh engine state of ``engine.init_engine_state``, built on the
+    mesh in its partitioned layout: each device builds only its own
+    guests' part, so the whole state never exists on one device."""
+    return _init_fn(spec.canonical(), mesh)(_mesh_tables(spec, mesh))
+
+
+@lru_cache(maxsize=16)
+def _partition_fn(spec, mesh):
+    cfg = spec.cfg
+    R = cfg.hp_ratio
+    part = host_partition(spec, mesh_size(mesh))
+
+    def body(state, tab):
+        T = _device_tables(part, tab)
+        i = jnp.arange(part.l_loc, dtype=jnp.int32)
+        lv = i < T["l_hi"] - T["l_lo"]
+        li = jnp.where(lv, T["l_lo"] + i, 0)
+
+        def rows(x, fill):
+            return jnp.where(lv, x[li], fill).astype(x.dtype)
+
+        j = jnp.arange(part.h_loc * R, dtype=jnp.int32)
+        hv = j < (T["hp_hi"] - T["hp_lo"]) * R
+        r = state.rmap[jnp.where(hv, T["hp_lo"] * R + j, 0)]
+        loc = _slice_host_local(cfg, state, T["hp_ids"])
+        d0 = jax.lax.axis_index(AXIS) == 0
+        return PartitionedState(
+            gpt=rows(state.gpt - T["hp_lo"] * R, -1),
+            rmap=jnp.where(hv & (r != FREE), r - T["l_lo"], FREE),
+            guest_counts=rows(state.guest_counts, 0),
+            ipt_hist=rows(state.ipt_hist, 0),
+            block_table=loc["bt"], host_counts=loc["hc"], host_hist=loc["hh"],
+            last_touch_epoch=loc["lt"], region_epoch=loc["re"],
+            payload=loc["data"], epoch=state.epoch[None],
+            stats={k: jnp.where(d0, v, 0)[None]
+                   for k, v in state.stats.items()},
+        )
+
+    return _partitioned_fn(mesh, body, (P(), P(AXIS)), P(AXIS))
+
+
+def partition_state(spec, mesh, state: TieredState) -> PartitionedState:
+    """Lay a replicated state out partitioned over ``mesh`` (for small
+    fleets and tests: the whole state is an input on every device)."""
+    return _partition_fn(spec.canonical(), mesh)(
+        state, _mesh_tables(spec, mesh))
+
+
+@lru_cache(maxsize=16)
+def _gather_fn(spec, mesh):
+    cfg = spec.cfg
+    R = cfg.hp_ratio
+    part = host_partition(spec, mesh_size(mesh))
+
+    def body(ps, tab):
+        T = _device_tables(part, tab)
+        i = jnp.arange(part.l_loc, dtype=jnp.int32)
+        l_ids = jnp.where(i < T["l_hi"] - T["l_lo"], T["l_lo"] + i, -1)
+        j = jnp.arange(part.h_loc * R, dtype=jnp.int32)
+        g_ids = jnp.where(j < (T["hp_hi"] - T["hp_lo"]) * R,
+                          T["hp_lo"] * R + j, -1)
+        loc = _carry_in(ps)["loc"]
+        hp_ids = T["hp_ids"]
+        contrib = dict(
+            gpt=_scatter_zero(ps.gpt + T["hp_lo"] * R, l_ids, cfg.n_logical),
+            rmap=_scatter_zero(
+                jnp.where(ps.rmap != FREE, ps.rmap + T["l_lo"], FREE), g_ids,
+                cfg.n_gpa),
+            guest_counts=_scatter_zero(ps.guest_counts, l_ids, cfg.n_logical),
+            ipt_hist=_scatter_zero(ps.ipt_hist, l_ids, cfg.n_logical),
+            bt=_scatter_zero(loc["bt"], hp_ids, cfg.n_gpa_hp),
+            hc=_scatter_zero(loc["hc"], hp_ids, cfg.n_gpa_hp),
+            hh=_scatter_zero(loc["hh"], hp_ids, cfg.n_gpa_hp),
+            lt=_scatter_zero(loc["lt"], hp_ids, cfg.n_gpa_hp),
+            re=_scatter_zero(loc["re"], hp_ids, cfg.n_gpa_hp),
+            near=_pool_contrib(cfg, loc, hp_ids, near=True),
+            far=_pool_contrib(cfg, loc, hp_ids, near=False),
+            stats={k: v[0] for k, v in ps.stats.items()},
+        )
+        m = _psum_counted("host_gather", contrib)
+        slot_owner = jnp.zeros((cfg.n_slots,), jnp.int32).at[m["bt"]].set(
+            jnp.arange(cfg.n_gpa_hp, dtype=jnp.int32))
+        return TieredState(
+            gpt=m["gpt"], rmap=m["rmap"], block_table=m["bt"],
+            slot_owner=slot_owner,
+            near_pool=_from_bits(m["near"], jnp.zeros((), cfg.dtype)),
+            far_pool=_from_bits(m["far"], jnp.zeros((), cfg.dtype)),
+            guest_counts=m["guest_counts"], ipt_hist=m["ipt_hist"],
+            host_counts=m["hc"], host_hist=m["hh"], last_touch_epoch=m["lt"],
+            region_epoch=m["re"], epoch=ps.epoch[0], stats=m["stats"],
+        )
+
+    return _partitioned_fn(mesh, body, (P(AXIS), P(AXIS)), P())
+
+
+def gather_state(spec, mesh, state: PartitionedState) -> TieredState:
+    """The explicit gather: the replicated TieredState of a partitioned one,
+    on every device, with ``slot_owner`` derived as the block table's
+    inverse -- exactly the inverse :func:`tiering.swap_blocks` maintains.
+    One psum of ownership-placed contributions (segments for guest arrays,
+    block ranges for host arrays, bit patterns for the pools). For tests
+    and small fleets: it holds the whole state on every device."""
+    with jax.profiler.TraceAnnotation("tier.gather"):
+        return _gather_fn(spec.canonical(), mesh)(
+            state, _mesh_tables(spec, mesh))
+
+
+def host_arrays(spec, state: PartitionedState) -> dict[str, np.ndarray]:
+    """The global guest page table and host block table of a partitioned
+    state, assembled on the host from each device's block (no device ever
+    holds them whole)."""
+    with jax.profiler.TraceAnnotation("tier.gather"):
+        n = n_shards_of(state)
+        part = host_partition(spec, n)
+        R = spec.cfg.hp_ratio
+        gpt = np.asarray(state.gpt).reshape(n, part.l_loc)
+        bt = np.asarray(state.block_table).reshape(n, part.h_loc)
+        return dict(
+            gpt=np.concatenate([
+                gpt[d, : part.l_hi[d] - part.l_lo[d]] + part.hp_lo[d] * R
+                for d in range(n)]).astype(np.int32),
+            block_table=np.concatenate([
+                bt[d, : part.hp_hi[d] - part.hp_lo[d]] for d in range(n)]),
+        )
+
+
+@lru_cache(maxsize=16)
+def _read_fn(spec, mesh):
+    cfg = spec.cfg
+    R = cfg.hp_ratio
+    part = host_partition(spec, mesh_size(mesh))
+
+    def body(payload, gpa, tab):
+        T = _device_tables(part, tab)
+        h = gpa // R
+        own = (h >= T["hp_lo"]) & (h < T["hp_hi"])
+        row = jnp.where(own, (h - T["hp_lo"]) * R + gpa % R, 0)
+        vals = payload.reshape(-1, cfg.base_elems)[row]
+        return jax.lax.psum(jnp.where(own[:, None], _bits(vals), 0), AXIS)
+
+    return _partitioned_fn(mesh, body, (P(AXIS), P(), P(AXIS)), P())
+
+
+def read_pages(spec, mesh, state: PartitionedState, gpa) -> np.ndarray:
+    """``dtype[len(gpa), base_elems]``: the payload of global GPA pages
+    ``gpa``, each read on the device that owns its huge page."""
+    bits = _read_fn(spec.canonical(), mesh)(
+        state.payload, jnp.asarray(gpa, jnp.int32), _mesh_tables(spec, mesh))
+    return np.asarray(_from_bits(bits, jnp.zeros((), spec.cfg.dtype)))

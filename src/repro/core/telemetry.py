@@ -32,23 +32,35 @@ from repro.core.types import GpacConfig, TieredState
 BACKENDS = ("ipt", "pebs", "damon")
 
 _BACKENDS: dict[str, Callable] = {}
+_PAGE_LOCAL: set[str] = set()
 
 
-def register_backend(name: str, fn: Callable | None = None):
+def register_backend(name: str, fn: Callable | None = None, *,
+                     page_local: bool = False):
     """Register a hotness classifier ``fn(cfg, state, **kw) ->
     bool[n_logical]``; usable as ``@register_backend("name")``. The name
-    becomes valid everywhere a ``backend=`` string is accepted."""
+    becomes valid everywhere a ``backend=`` string is accepted.
+    ``page_local`` declares that a page's verdict reads only that page's
+    own counters, so any slice of the pages classifies on its own."""
     if fn is None:
-        return lambda f: register_backend(name, f)
+        return lambda f: register_backend(name, f, page_local=page_local)
     if name in _BACKENDS:
         raise ValueError(f"telemetry backend {name!r} already registered")
     _BACKENDS[name] = fn
+    if page_local:
+        _PAGE_LOCAL.add(name)
     return fn
 
 
 def backends() -> tuple[str, ...]:
     """Names of all registered telemetry backends."""
     return tuple(_BACKENDS)
+
+
+def is_page_local(name: str) -> bool:
+    """Whether backend ``name`` classifies each page from its own counters
+    alone (see :func:`register_backend`)."""
+    return name in _PAGE_LOCAL
 
 
 def end_window(cfg: GpacConfig, state: TieredState) -> TieredState:
@@ -112,7 +124,7 @@ def hot_mask_damon(
     return jnp.repeat(region_hot, region_pages)[:n]
 
 
-register_backend("ipt", hot_mask_ipt)
+register_backend("ipt", hot_mask_ipt, page_local=True)
 register_backend("pebs", hot_mask_pebs)
 register_backend("damon", hot_mask_damon)
 

@@ -167,6 +167,72 @@ class TieredState:
     stats: dict  # running counters (see init_state)
 
 
+@partial(
+    jax.tree_util.register_dataclass,
+    data_fields=(
+        "gpt",
+        "rmap",
+        "guest_counts",
+        "ipt_hist",
+        "block_table",
+        "host_counts",
+        "host_hist",
+        "last_touch_epoch",
+        "region_epoch",
+        "payload",
+        "epoch",
+        "stats",
+    ),
+    meta_fields=(),
+)
+@dataclasses.dataclass
+class PartitionedState:
+    """The host-partitioned engine's state, kept partitioned over a 1-D
+    ``"guest"`` mesh between calls (``engine.run_sharded``, DESIGN.md §11).
+
+    Every array's leading axis is ``n_shards`` equal blocks, one per device
+    (``repro.core.sharding.HostPartition`` gives their sizes); device ``d``
+    holds only its own guests. Guest-major arrays are in the device's own
+    coordinates: logical page ``l_lo[d] + i`` is row ``i`` and ``gpt`` holds
+    GPA pages less ``hp_lo[d] * hp_ratio`` (``rmap`` the inverse), ``-1`` /
+    ``FREE`` in padding rows. Host arrays hold the device's block range
+    ``[hp_lo[d], hp_hi[d])``; ``block_table`` keeps global slot ids (the
+    near tier is shared), ``n_gpa_hp`` in padding rows, and ``payload`` row
+    ``h - hp_lo[d]`` holds huge page ``h``'s bytes, which equal the
+    replicated ``pools[block_table[h]]``. There is no ``slot_owner``: it is
+    the block table's inverse, derived where it is needed. ``epoch`` holds
+    the same value on every device; ``stats`` holds each device's share,
+    summing to the replicated counters.
+    """
+
+    gpt: jax.Array  # int32[n_shards * l_loc]
+    rmap: jax.Array  # int32[n_shards * h_loc * hp_ratio]
+    guest_counts: jax.Array  # int32[n_shards * l_loc]
+    ipt_hist: jax.Array  # uint8[n_shards * l_loc]
+    block_table: jax.Array  # int32[n_shards * h_loc]
+    host_counts: jax.Array  # int32[n_shards * h_loc]
+    host_hist: jax.Array  # uint8[n_shards * h_loc]
+    last_touch_epoch: jax.Array  # int32[n_shards * h_loc]
+    region_epoch: jax.Array  # int32[n_shards * h_loc]
+    payload: jax.Array  # dtype[n_shards * h_loc, hp_ratio, base_elems]
+    epoch: jax.Array  # int32[n_shards]
+    stats: dict  # int32[n_shards] each
+
+
+# the running int32 counters of ``TieredState.stats``
+STATS = (
+    "consolidated_pages",
+    "consolidation_calls",
+    "consolidation_enomem",
+    "copied_bytes",
+    "promoted_blocks",
+    "demoted_blocks",
+    "near_hits",
+    "far_hits",
+    "tlb_shootdowns",
+)
+
+
 def init_state(cfg: GpacConfig, fill: jax.Array | None = None) -> TieredState:
     """Fresh identity-mapped state.
 
@@ -198,17 +264,7 @@ def init_state(cfg: GpacConfig, fill: jax.Array | None = None) -> TieredState:
         last_touch_epoch=jnp.zeros((cfg.n_gpa_hp,), jnp.int32),
         region_epoch=jnp.full((cfg.n_gpa_hp,), -1, jnp.int32),
         epoch=jnp.zeros((), jnp.int32),
-        stats=dict(
-            consolidated_pages=jnp.zeros((), jnp.int32),
-            consolidation_calls=jnp.zeros((), jnp.int32),
-            consolidation_enomem=jnp.zeros((), jnp.int32),
-            copied_bytes=jnp.zeros((), jnp.int32),
-            promoted_blocks=jnp.zeros((), jnp.int32),
-            demoted_blocks=jnp.zeros((), jnp.int32),
-            near_hits=jnp.zeros((), jnp.int32),
-            far_hits=jnp.zeros((), jnp.int32),
-            tlb_shootdowns=jnp.zeros((), jnp.int32),
-        ),
+        stats={k: jnp.zeros((), jnp.int32) for k in STATS},
     )
     if fill is not None:
         from repro.core import address_space as asp

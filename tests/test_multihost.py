@@ -315,10 +315,11 @@ class TestCollectiveAccounting:
         mesh = sharding.guest_mesh(2)
         synth = engine.SynthTrace(n_windows=2, accesses_per_window=64)
         sharding.reset_collective_bytes()
+        # a replicated state in: partitioned once, gathered once at the end
         engine.run_sharded(spec, state, synth, mesh=mesh, host_sharded=True)
         rec = sharding.collective_bytes()
         assert rec.get("host_exchange", 0) > 0
-        assert rec.get("host_chunk_exit", 0) > 0
+        assert rec.get("host_gather", 0) > 0
         sharding.reset_collective_bytes()
         assert sharding.collective_bytes() == {}
 
